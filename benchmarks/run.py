@@ -17,6 +17,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from benchmarks import bench_walltime, suite  # noqa: E402
+from repro.launch import compile_cache  # noqa: E402
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "experiments", "bench")
 
@@ -289,6 +290,7 @@ def main():
                     help="directory for the per-figure BENCH_*.json files "
                          "written by --all-tiny / --all-full")
     args = ap.parse_args()
+    compile_cache.enable()
 
     all_recs = {}
     if args.all_tiny or args.all_full:

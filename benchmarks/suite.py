@@ -16,6 +16,7 @@ from benchmarks import cost_model as cm
 from repro.core import dispatch, masks
 from repro.core.bsr import BlockSparseMatrix
 from repro.core.partitioner import pack_tiles
+from repro.launch.mesh import make_mesh
 
 BATCHES = [64, 256, 1024, 4096, 16384]
 
@@ -333,7 +334,7 @@ def tp_crossover(tiny: bool = False):
 
     q_model = 8
     q_meas = min(q_model, len(jax.devices()))
-    mesh = (jax.make_mesh((q_meas,), ("model",)) if q_meas >= 2
+    mesh = (make_mesh((q_meas,), ("model",)) if q_meas >= 2
             else None)
     recs = []
     b = 16
@@ -597,18 +598,6 @@ def skewed_patterns(tiny: bool = False):
 
 # -- serving: sustained throughput at a latency SLO (PR 10) -------------------------------
 
-def _sparsify_ffn(cfg, density):
-    """The paper's static block-sparse FFN applied to a dense config --
-    the serving benchmark's sparse arm prices the stack the engine
-    would actually serve."""
-    import dataclasses
-    groups = tuple(
-        (tuple(dataclasses.replace(s, ffn="sparse")
-               for s in period), rep)
-        for period, rep in cfg.groups)
-    return dataclasses.replace(cfg, groups=groups, ffn_density=density)
-
-
 def _serve_sim(shapes, buckets, lens, max_new, batch):
     """Deterministic continuous-batching simulation on a cost-model
     virtual clock: admissions pay the bucketed prefill price, every
@@ -684,7 +673,7 @@ def serving_throughput(tiny: bool = False):
         lens = rng.integers(16, max_len - 1, size=n_req)
         dense_rps = None
         for ffn, c in (("dense", cfg),
-                       ("sparse_1_8", _sparsify_ffn(cfg, 1 / 8))):
+                       ("sparse_1_8", configs.sparse_ffn(cfg, 1 / 8))):
             shapes = _stack_shapes(c)
             buckets = _auto_buckets(max_len - 1, shapes, 0.5)
             best = None

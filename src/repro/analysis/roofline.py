@@ -1,15 +1,18 @@
-"""Roofline terms from dry-run artifacts (TPU v5e target constants).
+"""Roofline terms, against one table of published per-chip peaks.
 
     compute term    = HLO_FLOPs / peak_FLOP/s          (per chip)
     memory term     = HLO_bytes / HBM_bw               (per chip)
     collective term = collective_bytes / link_bw       (per chip)
 
 The analyzer inputs are already per-device (post-SPMD module), so no
-further division by chip count is needed.
+further division by chip count is needed.  ``PEAKS`` is keyed by the
+``device_kind`` JAX reports; a TPU kind that is not in it is an error,
+and a device that is not a TPU has no peaks (no roofline is reported).
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Optional
 
 
 @dataclass(frozen=True)
@@ -20,10 +23,35 @@ class HwSpec:
     ici_bw: float
 
 
-V5E = HwSpec("tpu-v5e", 197e12, 819e9, 50e9)
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s of
+# HBM bandwidth, 1,600 Gbit/s of interchip interconnect (4 links of
+# 50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": HwSpec("TPU v5 lite", 197e12, 819e9, 50e9),
+}
+V5E = PEAKS["TPU v5 lite"]      # the target of the dry-run projections
 
 
-def roofline_terms(cost: dict, hw: HwSpec = V5E, *, model_flops_per_device:
+def peaks_for(platform: str, device_kind: str) -> Optional[HwSpec]:
+    """Peaks of a device as JAX names it: the ``PEAKS`` entry of a TPU
+    kind, ``None`` off the TPU.  An unlisted TPU kind raises."""
+    if platform != "tpu":
+        return None
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for TPU kind {device_kind!r};"
+                         f" add it to analysis.roofline.PEAKS (known: "
+                         f"{sorted(PEAKS)})")
+    return PEAKS[device_kind]
+
+
+def device_peaks() -> Optional[HwSpec]:
+    """``peaks_for`` the first device of the default backend."""
+    import jax
+    d = jax.devices()[0]
+    return peaks_for(d.platform, d.device_kind)
+
+
+def roofline_terms(cost: dict, hw: HwSpec, *, model_flops_per_device:
                    float | None = None) -> dict:
     t_compute = cost["flops"] / hw.peak_flops_bf16
     t_memory = cost["bytes"] / hw.hbm_bw
@@ -43,7 +71,7 @@ def roofline_terms(cost: dict, hw: HwSpec = V5E, *, model_flops_per_device:
     return out
 
 
-def route_efficiency(est_seconds: float, cost: dict, hw: HwSpec = V5E, *,
+def route_efficiency(est_seconds: float, cost: dict, hw: HwSpec, *,
                      flag_headroom: float = 2.0) -> dict:
     """How close a route's (estimated or measured) time sits to its
     roofline bound for the work in ``cost`` (an analyzer-style dict:

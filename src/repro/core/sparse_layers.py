@@ -88,13 +88,20 @@ class SparseLinear:
                                  self.block_size)
 
     def _plan_ctx(self):
+        # the caller's ambient plan policy (a serving engine's pool, mesh
+        # and forward-only flag, or a forced reference mode) holds unless
+        # this layer pins a route of its own
         from repro import sparse as sparse_api
-        mode = (f"static_{self.backend}"
-                if self.backend in ("xla", "pallas")  # historical names
-                else self.backend)
-        return sparse_api.PlanContext(mode=mode,
-                                      grad_mode=self.grad_backend,
-                                      sddmm_mode=self.sddmm_backend)
+        over = {}
+        if self.backend != "auto":
+            over["mode"] = (f"static_{self.backend}"
+                            if self.backend in ("xla", "pallas")  # old names
+                            else self.backend)
+        if self.grad_backend != "auto":
+            over["grad_mode"] = self.grad_backend
+        if self.sddmm_backend != "auto":
+            over["sddmm_mode"] = self.sddmm_backend
+        return dataclasses.replace(sparse_api.current_ctx(), **over)
 
     def apply(self, params, x: jax.Array) -> jax.Array:
         # plan-first: the pattern analysis + route decision happen once

@@ -28,19 +28,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.core.partitioner import ShardedBlocks
-
-
-def _shard_map():
-    """jax moved shard_map out of experimental around 0.5/0.6; support
-    both homes (the repo floor is jax>=0.4.30)."""
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:              # jax >= 0.6: top-level only
-        from jax import shard_map
-    return shard_map
 
 
 def shard_map_executable(mesh, axis: str, q: int) -> bool:
@@ -50,12 +41,7 @@ def shard_map_executable(mesh, axis: str, q: int) -> bool:
     device count can only execute the gspmd lowering."""
     if mesh is None or axis not in getattr(mesh, "axis_names", ()):
         return False
-    try:
-        # AbstractMesh either lacks .devices or raises ValueError from
-        # the property (jax-version dependent) -- both mean "no devices"
-        if mesh.devices is None:
-            return False
-    except (AttributeError, ValueError):
+    if isinstance(mesh, AbstractMesh):   # shapes only, no devices
         return False
     return int(mesh.shape[axis]) == int(q)
 
@@ -90,9 +76,9 @@ def tp_spmm_shard_map(sb: ShardedBlocks, x: jax.Array, *, mesh,
                         mb=mb, b=b)
         return jax.lax.psum(y, axis)
 
-    fn = _shard_map()(shard_fn, mesh=mesh,
-                      in_specs=(P(axis), P(axis), P(axis), P()),
-                      out_specs=P(), check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P(axis), P(axis), P(axis), P()),
+                       out_specs=P(), check_vma=False)
     return fn(sb.values, sb.row_idx, sb.col_idx, x)
 
 

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 NEG_INF = -1e30
 
@@ -104,7 +103,7 @@ def bs_attn_call(tile_rows, tile_cols, q, k, v, *, bq: int, bkv: int,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tile_rows, tile_cols, q, k, v)

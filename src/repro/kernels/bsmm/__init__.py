@@ -3,7 +3,8 @@ from repro.kernels.bsmm.ref import bsmm_ref  # noqa: F401
 from repro.kernels.contract import KernelContract, register
 
 # static block-sparse SpMM: the BSR operand fixes m % b == k % b == 0 by
-# construction; _pick_tiles shrinks tm/tk/tn to divisors, so n is free
+# construction; tm/tk are block-multiple divisors of m/k, and n is
+# zero-padded to a multiple of tn (kernels.tiling), so n is free
 CONTRACT = register(KernelContract(
     kernel="bsmm",
     routes=("static_pallas",),
@@ -11,8 +12,8 @@ CONTRACT = register(KernelContract(
     min_block=1,
     max_block=128,
     divisibility=("m % b == 0", "k % b == 0"),
-    grid="(m // tm) x (n // tn), tm/tk/tn MXU-aligned divisors from "
-         "_pick_tiles; inner walk over the row's packed tiles",
+    grid="(m // tm) x (n_padded // tn), tm/tk/tn from _pick_tiles; "
+         "inner walk over the row's packed tiles",
     capacity="exact",
     pallas=True,
 ))

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _bsmm_balanced_kernel(rows_ref, cols_ref, slots_ref, a_ref, x_ref,
                           o_ref, acc_ref):
@@ -92,7 +90,7 @@ def bsmm_balanced_call(visit_rows, visit_cols, visit_slot, tiles, x, *,
         out_shape=jax.ShapeDtypeStruct((grid_m * tm, n), out_dtype),
         # bins write disjoint row-tile sets (pads keep the bin's own last
         # row), so the bin axis is safely parallel
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(visit_rows, visit_cols, visit_slot, tiles, x)

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _bsmm_kernel(rows_ref, cols_ref, a_ref, x_ref, o_ref, acc_ref):
     del cols_ref  # consumed by the index maps
@@ -78,7 +76,7 @@ def bsmm_call(tile_rows, tile_cols, tiles, x, *, tm: int, tk: int, tn: int,
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((grid_m * tm, n), out_dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tile_rows, tile_cols, tiles, x)

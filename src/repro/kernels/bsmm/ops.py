@@ -9,37 +9,36 @@ from repro.core.partitioner import (BalancedPacking, PackingPlan,
                                     plan_packing_balanced)
 from repro.kernels.bsmm.balanced import bsmm_balanced_call
 from repro.kernels.bsmm.bsmm import bsmm_call
+from repro.kernels.tiling import SUBLANE, dim_tile, lane_padded
+
+
+def _block_tile(dim: int, b: int) -> int:
+    """MXU-aligned block-multiple divisor of ``dim`` (shrunk for small
+    problems); the whole ``dim`` when that divisor is not
+    sublane-aligned."""
+    t = min(128, dim) if dim % 128 else 128
+    t = max(b, t - t % b)
+    while dim % t:
+        t //= 2
+    return t if t == dim or t % SUBLANE == 0 else dim
 
 
 def _pick_tiles(m: int, k: int, n: int, b: int):
-    """MXU-aligned tile sizes, shrunk for small problems."""
-    tm = min(128, m) if m % 128 else 128
-    tk = min(128, k) if k % 128 else 128
-    tn = min(128, n) if n % 128 else 128
-    # keep divisibility with the logical block
-    tm = max(b, tm - tm % b)
-    tk = max(b, tk - tk % b)
-    while m % tm:
-        tm //= 2
-    while k % tk:
-        tk //= 2
-    while n % tn:
-        tn //= 2
-    return max(tm, 1), max(tk, 1), max(tn, 1)
+    """``(tm, tk, tn)``: block-multiple row/contraction tiles dividing
+    ``m`` and ``k``; ``tn`` from ``tiling.dim_tile`` (the executors pad
+    ``n`` to a multiple of it)."""
+    return _block_tile(m, b), _block_tile(k, b), dim_tile(n)[0]
 
 
 def bsmm_packed(packing: TilePacking, x, *, tn: int | None = None,
                 interpret: bool = False):
     """SpMM from a pre-packed tile set (production path: pack once at
     weight-load, multiply every step)."""
-    m, k = packing.shape
-    n = x.shape[-1]
-    tn = tn or _pick_tiles(m, k, n, packing.tk)[2]
-    return bsmm_call(jnp.asarray(packing.tile_rows),
-                     jnp.asarray(packing.tile_cols),
-                     packing.values, x,
-                     tm=packing.tm, tk=packing.tk, tn=tn,
-                     grid_m=packing.grid[0], interpret=interpret)
+    return lane_padded(
+        lambda xp, tn: bsmm_call(
+            jnp.asarray(packing.tile_rows), jnp.asarray(packing.tile_cols),
+            packing.values, xp, tm=packing.tm, tk=packing.tk, tn=tn,
+            grid_m=packing.grid[0], interpret=interpret), x, tn)
 
 
 def bsmm_from_plan(meta: PackingPlan, values, x, *, tn: int | None = None,
@@ -48,14 +47,12 @@ def bsmm_from_plan(meta: PackingPlan, values, x, *, tn: int | None = None,
     pattern metadata is a baked host constant, only the value relayout
     (``pack_values``) runs per call.  This is the ``repro.sparse``
     plan-execute path for the ``static_pallas`` route."""
-    m, k = meta.shape
-    n = x.shape[-1]
-    tn = tn or _pick_tiles(m, k, n, meta.tk)[2]
     tiles = pack_values(meta, values)
-    return bsmm_call(jnp.asarray(meta.tile_rows),
-                     jnp.asarray(meta.tile_cols), tiles, x,
-                     tm=meta.tm, tk=meta.tk, tn=tn,
-                     grid_m=meta.grid[0], interpret=interpret)
+    return lane_padded(
+        lambda xp, tn: bsmm_call(
+            jnp.asarray(meta.tile_rows), jnp.asarray(meta.tile_cols), tiles,
+            xp, tm=meta.tm, tk=meta.tk, tn=tn, grid_m=meta.grid[0],
+            interpret=interpret), x, tn)
 
 
 def bsmm_balanced_from_plan(meta: BalancedPacking, values, x, *,
@@ -67,17 +64,15 @@ def bsmm_balanced_from_plan(meta: BalancedPacking, values, x, *,
     the value relayout (``pack_values``, identical to the uniform
     route's) plus the appended zero pad tile run."""
     base = meta.base
-    m, k = base.shape
-    n = x.shape[-1]
-    tn = tn or _pick_tiles(m, k, n, base.tk)[2]
     tiles = pack_values(base, values)
     tiles = jnp.concatenate(
         [tiles, jnp.zeros((1, base.tm, base.tk), tiles.dtype)])
-    return bsmm_balanced_call(jnp.asarray(meta.visit_rows),
-                              jnp.asarray(meta.visit_cols),
-                              jnp.asarray(meta.visit_slot), tiles, x,
-                              tm=base.tm, tk=base.tk, tn=tn,
-                              grid_m=base.grid[0], interpret=interpret)
+    return lane_padded(
+        lambda xp, tn: bsmm_balanced_call(
+            jnp.asarray(meta.visit_rows), jnp.asarray(meta.visit_cols),
+            jnp.asarray(meta.visit_slot), tiles, xp, tm=base.tm,
+            tk=base.tk, tn=tn, grid_m=base.grid[0], interpret=interpret),
+        x, tn)
 
 
 def bsmm_balanced(bsr: BlockSparseMatrix, x, *, tm: int | None = None,

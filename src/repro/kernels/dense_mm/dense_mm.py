@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _mm_kernel(a_ref, b_ref, o_ref, acc_ref):
     kj = pl.program_id(2)
@@ -51,7 +49,7 @@ def dense_mm_call(a, b, *, tm: int, tk: int, tn: int,
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, kj: (i, j)),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -2,17 +2,16 @@
 from __future__ import annotations
 
 from repro.kernels.dense_mm.dense_mm import dense_mm_call
+from repro.kernels.tiling import dim_tile, pad_to
 
 
-def _fit(dim, pref=128):
-    v = pref
-    while dim % v:
-        v //= 2
-    return max(v, 1)
-
-
-def dense_mm(a, b, *, tm=None, tk=None, tn=None, interpret: bool = False):
+def dense_mm(a, b, *, interpret: bool = False):
+    """``a [m, k] @ b [k, n]``.  Each dimension is one block up to 128,
+    else 128-wide tiles over its zero-padded extent."""
     m, k = a.shape
     _, n = b.shape
-    return dense_mm_call(a, b, tm=tm or _fit(m), tk=tk or _fit(k),
-                         tn=tn or _fit(n), interpret=interpret)
+    (tm, mp), (tk, kp), (tn, np_) = dim_tile(m), dim_tile(k), dim_tile(n)
+    a = pad_to(pad_to(a, 0, mp), 1, kp)
+    b = pad_to(pad_to(b, 0, kp), 1, np_)
+    y = dense_mm_call(a, b, tm=tm, tk=tk, tn=tn, interpret=interpret)
+    return y[:m, :n] if (mp, np_) != (m, n) else y

@@ -3,7 +3,8 @@ from repro.kernels.dsmm.ref import dsmm_ref  # noqa: F401
 from repro.kernels.contract import KernelContract, register
 
 # dynamic slot-encoded SpMM: runtime pattern in a fixed nnz_max slot
-# array (plus one coverage slot per block-row); tn shrinks to divide n
+# array (plus one coverage slot per block-row); n is zero-padded to a
+# multiple of tn, and a block edge under 8 to 8 (ops.slot_walk)
 CONTRACT = register(KernelContract(
     kernel="dsmm",
     routes=("dynamic_pallas",),

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _dsmm_kernel(rows_ref, cols_ref, a_ref, x_ref, o_ref, acc_ref):
     del cols_ref
@@ -80,7 +78,7 @@ def dsmm_call(rows, cols, values, x, *, b: int, tn: int, grid_m: int,
             scratch_shapes=[pltpu.VMEM((b, tn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((grid_m * b, n), out_dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rows, cols, values, x)

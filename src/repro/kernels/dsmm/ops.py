@@ -5,6 +5,7 @@ import jax.numpy as jnp
 
 from repro.core.dynamic_sparse import DynamicOperand
 from repro.kernels.dsmm.dsmm import dsmm_call
+from repro.kernels.tiling import SUBLANE, lane_padded, pad_to
 
 
 def _encode_slots(op: DynamicOperand):
@@ -26,17 +27,30 @@ def _encode_slots(op: DynamicOperand):
     return rows[order], cols[order], vals[order]
 
 
+def slot_walk(rows, cols, vals, x, *, b: int, grid_m: int,
+              tn: int | None = None, interpret: bool = False):
+    """Run the slot-walk kernel on encoded slots.  ``n`` is padded to the
+    lane tile; a block edge ``b`` that is not sublane-aligned is padded
+    to the next multiple of 8 (zero rows/columns inside every block), so
+    every block size the contract admits compiles."""
+    bp = -(-b // SUBLANE) * SUBLANE
+    if bp != b:
+        k, n = x.shape
+        vals = pad_to(pad_to(vals, 1, bp), 2, bp)
+        x = pad_to(x.reshape(k // b, b, n), 1, bp).reshape(-1, n)
+    y = lane_padded(lambda xp, tn: dsmm_call(
+        rows, cols, vals, xp, b=bp, tn=tn, grid_m=grid_m,
+        interpret=interpret), x, tn)
+    if bp != b:
+        y = y.reshape(grid_m, bp, -1)[:, :b].reshape(grid_m * b, -1)
+    return y
+
+
 def dsmm(op: DynamicOperand, x, *, tn: int | None = None,
          interpret: bool = False):
     """Dynamic SpMM ``Y = decode(op) @ X`` through the Pallas kernel."""
-    m, k = op.shape
+    m, _ = op.shape
     b = op.block_size
-    n = x.shape[-1]
-    if tn is None:
-        tn = 128
-        while n % tn:
-            tn //= 2
-        tn = max(tn, 1)
     rows, cols, vals = _encode_slots(op)
-    return dsmm_call(rows, cols, vals, x, b=b, tn=tn, grid_m=m // b,
+    return slot_walk(rows, cols, vals, x, b=b, grid_m=m // b, tn=tn,
                      interpret=interpret)

@@ -20,7 +20,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core.dynamic_sparse import DynamicOperand
-from repro.kernels.dsmm.dsmm import dsmm_call
+from repro.kernels.dsmm.ops import slot_walk
 from repro.kernels.gmm.ops import (clamped_tiles_cap, grouped_tile_size,
                                    pack_tiles_device)
 
@@ -77,13 +77,8 @@ def balanced_spmm(op: DynamicOperand, x, *, tile: int | None = None,
     tiles_cap = max(1, tiles_cap)
     packed, stats = pack_tiles_device(op, tile=t, tiles_cap=tiles_cap,
                                       with_stats=return_stats)
-    n = x.shape[-1]
-    tn = 128
-    while n % tn:
-        tn //= 2
-    tn = max(tn, 1)
     rows, cols, vals = _encode_slots_balanced(packed, num_bins)
-    y = dsmm_call(rows, cols, vals, x, b=t, tn=tn, grid_m=m // t,
+    y = slot_walk(rows, cols, vals, x, b=t, grid_m=m // t,
                   interpret=interpret)
     if return_stats:
         return y, stats

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _gmm_kernel(ids_ref, x_ref, w_ref, o_ref, acc_ref):
     d = pl.program_id(2)
@@ -60,7 +58,7 @@ def gmm_call(expert_ids, x, w, *, tm: int, tf: int, td: int,
             scratch_shapes=[pltpu.VMEM((tm, tf), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((t_rows, f), out_dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(expert_ids, x, w)

@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.core.partitioner import PackingPlan
 from repro.kernels.sddmm.sddmm import sddmm_tiles_call
+from repro.kernels.tiling import SUBLANE, dim_tile, pad_to
 
 
 def sddmm_tile_size(m: int, k: int, b: int, limit: int = 128) -> int:
@@ -48,14 +49,21 @@ def grouped_sddmm(meta: PackingPlan, dy, x, *, tn: int | None = None,
     n = dy.shape[1]
     if x.shape[1] != n:
         raise ValueError(f"dy cols {n} != x cols {x.shape[1]}")
-    if tn is None:
-        tn = 128
-        while n % tn:
-            tn //= 2
-        tn = max(tn, 1)
+    # zero columns of the contraction (n) axis add nothing; a tile edge
+    # that is not sublane-aligned gets zero rows inside every tile
+    tn = tn or dim_tile(n)[0]
+    n_pad = -(-n // tn) * tn
+    tp = -(-t // SUBLANE) * SUBLANE
+
+    def prep(a):
+        a = pad_to(a, 1, n_pad)
+        if tp != t:
+            a = pad_to(a.reshape(-1, t, n_pad), 1, tp).reshape(-1, n_pad)
+        return a
     tiles = sddmm_tiles_call(jnp.asarray(meta.tile_rows, jnp.int32),
                              jnp.asarray(meta.tile_cols, jnp.int32),
-                             dy, x, t=t, tn=tn, interpret=interpret)
+                             prep(dy), prep(x), t=tp, tn=tn,
+                             interpret=interpret)[:, :t, :t]
     # host-metadata block extraction: tile stack -> [nnz, b, b] values
     rpb = t // b
     blocked = tiles.reshape(meta.num_tiles, rpb, b, rpb, b)

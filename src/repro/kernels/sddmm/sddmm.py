@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _sddmm_kernel(trows_ref, tcols_ref, dy_ref, x_ref, o_ref, acc_ref):
     del trows_ref, tcols_ref
@@ -82,7 +80,7 @@ def sddmm_tiles_call(tile_rows, tile_cols, dy, x, *, t: int, tn: int,
             scratch_shapes=[pltpu.VMEM((t, t), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((num_tiles, t, t), out_dtype),
-        compiler_params=compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tile_rows, tile_cols, dy, x)

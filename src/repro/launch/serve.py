@@ -16,6 +16,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.models.model import LM
 from repro.serve import Engine, Request
 
@@ -34,6 +35,7 @@ def main():
                     help="persistent autotune cache dir (repro.sparse): "
                          "restarts skip re-planning/re-measurement")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = configs.smoke(args.arch) if args.smoke else configs.get(args.arch)
     lm = LM(cfg)
@@ -54,12 +56,14 @@ def main():
         (r.uid, time.time() - t0)))
     total_toks = sum(len(r.output) for r in reqs)
     dt = time.time() - t0
+    dev = jax.devices()[0]
     for uid, t in done:
         r = next(r for r in reqs if r.uid == uid)
         print(f"[serve] req {uid}: {len(r.prompt)} prompt -> "
               f"{len(r.output)} tokens @ {t:.2f}s: {r.output[:6]}...")
     print(f"[serve] {len(reqs)} requests, {total_toks} tokens, "
-          f"{dt:.2f}s ({total_toks/dt:.1f} tok/s on CPU, "
+          f"{dt:.2f}s host wall-clock ({total_toks/dt:.1f} tok/s on "
+          f"{dev.platform} {dev.device_kind!r} x{len(jax.devices())}, "
           f"batch={args.batch}, retained={args.retained})")
 
 

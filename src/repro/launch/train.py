@@ -29,6 +29,7 @@ import jax
 from repro import configs
 from repro.checkpoint import Checkpointer, latest_step, restore
 from repro.data import TokenPipeline
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import LM
 from repro.sharding import rules
@@ -106,6 +107,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--lr", type=float, default=1e-3)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = configs.smoke(args.arch) if args.smoke else configs.get(args.arch)
     hp = TrainHParams(peak_lr=args.lr, warmup_steps=max(1, args.steps // 10),

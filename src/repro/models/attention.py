@@ -166,11 +166,12 @@ def build_pair_schedule(nq: int) -> PairSchedule:
     return PairSchedule(rows, cols, tag, valid)
 
 
-def _attend_balanced_causal(q, k, v, *, scale, softcap, tile_q, tile_kv
-                            ) -> jax.Array:
+def _attend_balanced_causal(q, k, v, *, scale, softcap, tile_q, tile_kv,
+                            kv_len: Optional[int] = None) -> jax.Array:
     """Causal full attention via the folded pair schedule (see
     EXPERIMENTS.md §Perf: ~2x fewer score-tile visits than the row
-    schedule at identical output)."""
+    schedule at identical output).  Keys at or past ``kv_len`` are
+    padding and never attended."""
     b_, s, h, dh = q.shape
     nq = s // tile_q
     sched = build_pair_schedule(nq)
@@ -208,6 +209,8 @@ def _attend_balanced_causal(q, k, v, *, scale, softcap, tile_q, tile_kv
             ci = c * tile_kv + jax.lax.broadcasted_iota(
                 jnp.int32, (tile_q, tile_kv), 1)
             emask = (ri >= ci) & ok
+            if kv_len is not None:
+                emask &= ci < kv_len
             logits = jnp.where(emask[None, None], logits, NEG_INF)
             m_t, l_t, acc_t = m[t], l[t], acc[t]
             m_new = jnp.maximum(m_t, logits.max(axis=-1))
@@ -232,7 +235,9 @@ def _attend_balanced_causal(q, k, v, *, scale, softcap, tile_q, tile_kv
     # static inverse permutation: row r was emitted at flat slot inv[r]
     flat_rows = sched.rows.reshape(-1)
     inv = np.zeros(nq, np.int64)
-    inv[flat_rows] = np.arange(flat_rows.shape[0])
+    # an odd nq lists its middle row twice and feeds only the first slot,
+    # so the first occurrence must win
+    inv[flat_rows[::-1]] = np.arange(flat_rows.shape[0])[::-1]
     outs = outs[jnp.asarray(inv)]
     return outs.transpose(1, 0, 3, 2, 4).reshape(b_, s, h, dh)
 
@@ -245,10 +250,12 @@ def _attend_balanced_causal(q, k, v, *, scale, softcap, tile_q, tile_kv
 def _attend_scheduled(q, k, v, sched: AttnSchedule, *, scale: float,
                       causal: bool, window: int, softcap: Optional[float],
                       tile_q: int, tile_kv: int,
-                      global_prefix: int = 0) -> jax.Array:
+                      global_prefix: int = 0,
+                      kv_len: Optional[int] = None) -> jax.Array:
     """q: [B, S, H, dh]; k, v: [B, Skv, KV, dh] already head-repeated to H.
 
     Returns [B, S, H, dh].  fp32 softmax statistics, bf16 matmul inputs.
+    Keys at or past ``kv_len`` are padding and never attended.
     """
     b_, s, h, dh = q.shape
     skv = k.shape[1]
@@ -292,6 +299,8 @@ def _attend_scheduled(q, k, v, sched: AttnSchedule, *, scale: float,
                 emask &= ri >= ci
             if window > 0:
                 emask &= (ri - ci < window) | (ci < global_prefix)
+            if kv_len is not None:
+                emask &= ci < kv_len
             logits = jnp.where(emask[None, None], logits, NEG_INF)
             m_new = jnp.maximum(m, logits.max(axis=-1))
             alpha = jnp.exp(m - m_new)
@@ -329,6 +338,18 @@ def _repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
                             ).reshape(b_, s, kv * n_rep, dh)
 
 
+def _seq_tile(n: int, pref: int, floor: int = 128) -> tuple[int, int]:
+    """``(tile, padded_n)`` for a sequence axis of length ``n``: the
+    whole axis up to ``pref``; above it ``pref`` halved while it does not
+    divide ``n``, but never below ``floor`` -- the axis is zero-padded to
+    a multiple of the tile instead (halving an odd prefill length down
+    to a divisor gave one-token tiles and a scan of ``n**2 / 2`` steps)."""
+    t = min(pref, n)
+    while n % t and t // 2 >= floor:
+        t //= 2
+    return t, -(-n // t) * t
+
+
 def attend_train(q, k, v, *, causal: bool = True, window: int = 0,
                  global_prefix: int = 0, softcap: Optional[float] = None,
                  scale: Optional[float] = None, tile_q: int = 512,
@@ -340,22 +361,28 @@ def attend_train(q, k, v, *, causal: bool = True, window: int = 0,
     schedule so out-of-window tiles are never visited.
     """
     b_, s, h, dh = q.shape
-    kv_heads = k.shape[2]
+    skv, kv_heads = k.shape[1], k.shape[2]
     scale = scale if scale is not None else 1.0 / np.sqrt(dh)
     k = _repeat_kv(k, h // kv_heads)
     v = _repeat_kv(v, h // kv_heads)
-    tile_q = min(tile_q, s)
-    tile_kv = min(tile_kv, k.shape[1])
-    while s % tile_q:
-        tile_q //= 2
-    while k.shape[1] % tile_kv:
-        tile_kv //= 2
-    nq, nkv = s // tile_q, k.shape[1] // tile_kv
+    tile_q, s_pad = _seq_tile(s, tile_q)
+    tile_kv, skv_pad = _seq_tile(skv, tile_kv)
+    # zero padding at the end: padded query rows are dropped below, and
+    # padded keys are masked by ``kv_len``
+    def pad(a, n):
+        return jnp.pad(a, ((0, 0), (0, n - a.shape[1]), (0, 0), (0, 0)))
+    if s_pad != s:
+        q = pad(q, s_pad)
+    kv_len = None
+    if skv_pad != skv:
+        k, v, kv_len = pad(k, skv_pad), pad(v, skv_pad), skv
+    nq, nkv = s_pad // tile_q, skv_pad // tile_kv
     if (schedule == "balanced" and causal and window == 0
             and nq == nkv and tile_q == tile_kv and nq > 1):
-        return _attend_balanced_causal(q, k, v, scale=float(scale),
-                                       softcap=softcap, tile_q=tile_q,
-                                       tile_kv=tile_kv)
+        out = _attend_balanced_causal(q, k, v, scale=float(scale),
+                                      softcap=softcap, tile_q=tile_q,
+                                      tile_kv=tile_kv, kv_len=kv_len)
+        return out[:, :s]
     # a query's window can straddle one extra back tile: the earliest
     # visible key for the first row of tile i is i*tq - (window-1), so
     # floor((window-1)/tkv) + 1 back tiles (+1 for the strict-< builder)
@@ -363,10 +390,11 @@ def attend_train(q, k, v, *, causal: bool = True, window: int = 0,
     gt = -(-global_prefix // tile_kv) if global_prefix > 0 else 0
     sched = _causal_schedule(nq, nkv, wt, gt, tile_q, tile_kv,
                              False, causal)
-    return _attend_scheduled(q, k, v, sched, scale=float(scale),
-                             causal=causal, window=window, softcap=softcap,
-                             tile_q=tile_q, tile_kv=tile_kv,
-                             global_prefix=global_prefix)
+    out = _attend_scheduled(q, k, v, sched, scale=float(scale),
+                            causal=causal, window=window, softcap=softcap,
+                            tile_q=tile_q, tile_kv=tile_kv,
+                            global_prefix=global_prefix, kv_len=kv_len)
+    return out[:, :s]
 
 
 # ---------------------------------------------------------------------------

@@ -241,7 +241,6 @@ def _moe_shard_map(params, cfg, x, mesh, ba) -> tuple[jax.Array, MoEMetrics]:
       E/|model| experts, and contributes a partial [T_loc, D];
     * ONE psum over 'model' (bf16 if combine_dtype says so) combines.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     m = cfg.moe
     b_, s, d = x.shape
@@ -287,12 +286,12 @@ def _moe_shard_map(params, cfg, x, mesh, ba) -> tuple[jax.Array, MoEMetrics]:
     # (w_gate/w_up: D; w_down: F -- same rule as sharding/rules.py)
     w_spec = P("model", "data" if "data" in mesh.axis_names else None,
                None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None),
                   w_spec, w_spec, w_spec),
         out_specs=(P(bspec, None, None), P()),
-        check_rep=False)
+        check_vma=False)
     y, metrics = fn(x, params["router"]["w"], params["w_gate"],
                     params["w_up"], params["w_down"])
     if m.num_shared:

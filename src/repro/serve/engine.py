@@ -315,9 +315,12 @@ class Engine:
         state is untouched."""
         row = None
         for L in self.buckets:
+            # host arrays, as ``admit`` passes them: the jit cache keys
+            # on the argument type, so device arrays here would leave a
+            # second entry per bucket to fill while serving
             logits, row = self._prefill(
-                self.params, jnp.zeros((1, L), jnp.int32),
-                jnp.zeros((1,), jnp.int32))
+                self.params, np.zeros((1, L), np.int32),
+                np.zeros((1,), np.int32))
             logits.block_until_ready()
         if row is not None:
             jax.block_until_ready(
@@ -325,7 +328,12 @@ class Engine:
         logits, _ = self._decode(
             self.params, jnp.zeros((self.batch, 1), jnp.int32),
             self.caches, jnp.zeros((self.batch,), jnp.int32))
-        logits.block_until_ready()
+        self._next_tokens(logits)     # the step's device-side argmax
+
+    @staticmethod
+    def _next_tokens(logits) -> np.ndarray:
+        """Greedy token per slot: argmax on the device, ``[B]`` to host."""
+        return np.asarray(jnp.argmax(logits, axis=-1))
 
     # -- pricing ----------------------------------------------------------
     def _price(self, n_tokens: int) -> float:
@@ -483,9 +491,8 @@ class Engine:
             padded[0, :n] = prompt
         t0 = time.perf_counter()
         logits, row_caches = self._prefill(
-            self.params, jnp.asarray(padded),
-            jnp.asarray([n - 1], jnp.int32))
-        tok = int(np.asarray(logits[0]).argmax())
+            self.params, padded, np.asarray([n - 1], np.int32))
+        tok = int(np.asarray(logits)[0].argmax())
         dt = time.perf_counter() - t0
         self.caches = self._write_slot(self.caches, row_caches, slot)
         self.positions[slot] = n
@@ -530,7 +537,7 @@ class Engine:
         logits, self.caches = self._decode(
             self.params, jnp.asarray(tokens), self.caches,
             jnp.asarray(self.positions))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        nxt = self._next_tokens(logits)
         finished: List[Request] = []
         released: List[int] = []
         for slot, req in self.live.items():

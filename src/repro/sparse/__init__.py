@@ -31,6 +31,7 @@ from repro.sparse.plan import (  # noqa: F401
     cache_stats,
     capacity_report,
     configure,
+    current_ctx,
     evolve,
     evolve_plans,
     explain,

@@ -223,7 +223,8 @@ def plan_report() -> dict:
         per[rkey] = {
             "route": p.route, "source": p.source,
             "from_disk": p.from_disk, "op": p.spec.op,
-            "kind": p.spec.kind, "grad": grad,
+            "kind": p.spec.kind, "shape": (p.spec.m, p.spec.k, p.spec.n),
+            "grad": grad,
             "evolution": p.artifacts.get("evolution"),
         }
     planned = [r for r in per.values()
@@ -523,7 +524,10 @@ class MatmulPlan:
     def roofline(self, *, flag_headroom: float = 2.0) -> dict:
         """Per-route roofline efficiency over the raced forward
         candidates: how close each route's (estimated or measured) time
-        sits to the hardware bound for the work it executes.
+        sits to the hardware bound for the work it executes, against
+        the peaks of the device JAX runs on (``analysis.roofline.PEAKS``).
+        A device without published peaks (the CPU) gets no figures, and
+        ``unavailable`` says why.
 
         ``routes[r]["flagged"]`` marks routes leaving more than
         ``flag_headroom``x on the table; ``kernel_work`` collects them
@@ -532,14 +536,22 @@ class MatmulPlan:
         estimates are per-mesh collective times, priced by
         ``explain()["tp"]`` instead)."""
         from repro.analysis import roofline as roofline_lib
-        routes = {}
+        hw = roofline_lib.device_peaks()
+        rep = {"hw": hw.name if hw else None,
+               "flag_headroom": flag_headroom, "chosen": None,
+               "routes": {}, "kernel_work": []}
+        if hw is None:
+            d = jax.devices()[0]
+            rep["unavailable"] = (f"no published peaks for {d.platform} "
+                                  f"device {d.device_kind!r}")
+            return rep
         for route, est in self.est_seconds.items():
             if route in TP_ROUTES:
                 continue
             eff = roofline_lib.route_efficiency(
-                est, self.spec.roofline_cost(route),
+                est, self.spec.roofline_cost(route), hw,
                 flag_headroom=flag_headroom)
-            routes[route] = {
+            rep["routes"][route] = {
                 "achieved_us": round(eff["achieved_seconds"] * 1e6, 3),
                 "bound_us": round(eff["bound_seconds"] * 1e6, 3),
                 "dominant": eff["dominant"],
@@ -547,14 +559,10 @@ class MatmulPlan:
                 "headroom": round(eff["headroom"], 2),
                 "flagged": eff["flagged"],
             }
-        return {
-            "hw": roofline_lib.V5E.name,
-            "flag_headroom": flag_headroom,
-            "chosen": routes.get(self.route),
-            "routes": routes,
-            "kernel_work": sorted(r for r, e in routes.items()
-                                  if e["flagged"]),
-        }
+        rep["chosen"] = rep["routes"].get(self.route)
+        rep["kernel_work"] = sorted(r for r, e in rep["routes"].items()
+                                    if e["flagged"])
+        return rep
 
     def capacity_report(self) -> Optional[dict]:
         """Planned capacity + running overflow stats for this plan
@@ -1856,6 +1864,12 @@ def _resolve_ctx(ctx) -> PlanContext:
     if isinstance(ctx, dispatch.DispatchContext):
         return PlanContext.from_dispatch(ctx)
     return ctx
+
+
+def current_ctx() -> PlanContext:
+    """The ambient plan context (``use_ctx``), else the dispatch view of
+    the ambient dispatch context."""
+    return _resolve_ctx(None)
 
 
 def plan(operand_or_spec, n: Optional[int] = None, *, x=None,

@@ -72,6 +72,26 @@ def test_attend_train_softcap_noncausal():
                                rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("causal,schedule,window",
+                         [(True, "row", 0), (True, "balanced", 0),
+                          (True, "row", 100), (False, "row", 0)])
+def test_attend_train_unaligned_length(causal, schedule, window):
+    """An odd length (an engine's ``max_len - 1`` prefill bucket) keeps
+    128-row tiles over a zero-padded axis -- halving to a divisor would
+    give one-token tiles -- and padded keys are never attended."""
+    b, s, h, dh = 1, 383, 2, 32
+    q = jax.random.normal(jax.random.PRNGKey(0), (b, s, h, dh)) * 0.4
+    k = jax.random.normal(jax.random.PRNGKey(1), (b, s, h, dh)) * 0.4
+    v = jax.random.normal(jax.random.PRNGKey(2), (b, s, h, dh))
+    assert attn._seq_tile(s, 256) == (128, 384)
+    got = attn.attend_train(q, k, v, causal=causal, window=window,
+                            tile_q=256, tile_kv=256, schedule=schedule)
+    want = _naive(q, k, v, causal=causal, window=window)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-3, atol=2e-3)
+
+
 def test_attend_decode_matches_last_row():
     b, s, h, kv, dh = 2, 96, 4, 2, 32
     q = jax.random.normal(jax.random.PRNGKey(0), (b, 1, h, dh))

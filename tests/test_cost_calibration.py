@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro import sparse
-from repro.analysis import calibrate, report
+from repro.analysis import calibrate, report, roofline
 from repro.analysis.hlo_cost import sddmm_cost_dict, spmm_cost_dict
 from repro.analysis.roofline import V5E, route_efficiency
 from repro.core import dispatch
@@ -253,7 +253,7 @@ def test_cost_check_rc2_without_coeffs_file(tmp_path):
 def test_route_efficiency_at_bound():
     cost = {"flops": V5E.peak_flops_bf16, "bytes": 0,
             "collective_bytes": 0}           # exactly 1s compute bound
-    eff = route_efficiency(1.0, cost)
+    eff = route_efficiency(1.0, cost, V5E)
     assert eff["dominant"] == "compute"
     assert eff["efficiency"] == pytest.approx(1.0)
     assert eff["headroom"] == pytest.approx(1.0)
@@ -263,19 +263,27 @@ def test_route_efficiency_at_bound():
 def test_route_efficiency_flags_headroom():
     cost = {"flops": V5E.peak_flops_bf16, "bytes": 0,
             "collective_bytes": 0}
-    eff = route_efficiency(10.0, cost)
+    eff = route_efficiency(10.0, cost, V5E)
     assert eff["headroom"] == pytest.approx(10.0)
     assert eff["efficiency"] == pytest.approx(0.1)
     assert eff["flagged"]
-    assert not route_efficiency(10.0, cost, flag_headroom=20.0)["flagged"]
+    assert not route_efficiency(10.0, cost, V5E,
+                                flag_headroom=20.0)["flagged"]
 
 
 def test_route_efficiency_memory_bound():
     cost = {"flops": 1.0, "bytes": V5E.hbm_bw,
             "collective_bytes": 0}           # exactly 1s memory bound
-    eff = route_efficiency(2.0, cost)
+    eff = route_efficiency(2.0, cost, V5E)
     assert eff["dominant"] == "memory"
     assert eff["bound_seconds"] == pytest.approx(1.0)
+
+
+def test_peaks_keyed_by_device_kind():
+    assert roofline.peaks_for("tpu", "TPU v5 lite") is V5E
+    assert roofline.peaks_for("cpu", "cpu") is None
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks_for("tpu", "TPU v99")
 
 
 def test_spmm_sddmm_cost_dicts():
@@ -289,14 +297,19 @@ def test_spmm_sddmm_cost_dicts():
         assert d["collective_bytes"] == 0 and d["warnings"] == []
 
 
-def test_plan_explain_reports_roofline(tmp_path):
+def test_plan_explain_reports_roofline(tmp_path, monkeypatch):
     sparse.configure(str(tmp_path))
     try:
         p = sparse.plan(_bsr(), 64)
+        # the host CPU has no published peaks: no figures, and why
+        off = p.explain()["roofline"]
+        monkeypatch.setattr(roofline, "device_peaks", lambda: V5E)
         roof = p.explain()["roofline"]
     finally:
         sparse.reset()
         sparse.configure(None)
+    assert off["hw"] is None and off["chosen"] is None
+    assert not off["routes"] and "cpu" in off["unavailable"]
     assert roof["hw"] == V5E.name
     assert roof["chosen"] is not None
     assert roof["chosen"] == roof["routes"][p.route]
@@ -310,7 +323,8 @@ def test_plan_explain_reports_roofline(tmp_path):
     assert "roofline:" in sparse.format_plan(p)
 
 
-def test_roofline_report_totals(tmp_path):
+def test_roofline_report_totals(tmp_path, monkeypatch):
+    monkeypatch.setattr(roofline, "device_peaks", lambda: V5E)
     sparse.configure(str(tmp_path))
     try:
         sparse.plan(_bsr(), 64)

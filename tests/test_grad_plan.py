@@ -218,10 +218,11 @@ def test_plan_vjp_under_jit_and_vmap():
     # per-example grads: vmap over a batch of activations
     xb = jax.random.normal(jax.random.PRNGKey(7), (3, K, 8))
     gxb = jax.vmap(jax.grad(lambda x_: (p(v, x_) ** 2).sum()))(xb)
+    # vmap reorders the fp32 sums; at magnitudes ~250 one ulp is 3e-5,
+    # so the budget is relative to the gradient's max norm
     for i in range(3):
         gi = jax.grad(lambda x_: (p(v, x_) ** 2).sum())(xb[i])
-        np.testing.assert_allclose(np.asarray(gxb[i]), np.asarray(gi),
-                                   rtol=1e-5, atol=1e-5)
+        assert_close_for_dtype(gxb[i], gi, "float32", f"vmapped dx[{i}]")
 
 
 def test_plan_grad_accumulation_microbatch_scan():

@@ -6,17 +6,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.hlo_cost import analyze_hlo_text, parse_hlo
+from repro.launch.mesh import make_mesh
 
 
 def _compile(f, *sds):
     return jax.jit(f).lower(*sds).compile()
-
-
-def _xla_cost(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict in newer jax, a
-    one-element list of dicts in older releases."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
 
 
 def test_matches_xla_on_loop_free():
@@ -25,7 +19,7 @@ def test_matches_xla_on_loop_free():
     c = _compile(f, jax.ShapeDtypeStruct((128, 256), jnp.float32),
                  jax.ShapeDtypeStruct((256, 64), jnp.float32))
     mine = analyze_hlo_text(c.as_text())
-    xla = _xla_cost(c)
+    xla = c.cost_analysis()
     np.testing.assert_allclose(mine["flops"], xla["flops"], rtol=0.05)
 
 
@@ -42,7 +36,7 @@ def test_scan_trip_count_multiplied():
     assert not mine["warnings"]
     # XLA's own visitor counts the body once -- the reason this module
     # exists; if XLA ever fixes it, this assert flags the redundancy.
-    assert _xla_cost(c)["flops"] < expected / 2
+    assert c.cost_analysis()["flops"] < expected / 2
 
 
 def test_nested_scan():
@@ -249,7 +243,7 @@ def test_half_precision_byte_accounting():
 
 
 def test_collectives_counted_under_spmd():
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def f(a):

@@ -8,6 +8,7 @@ import numpy as np
 from repro.configs import qwen3_moe_30b_a3b
 from repro.models import moe as moe_lib
 from repro.models.moe import moe_apply, moe_init
+from repro.launch.mesh import make_mesh
 
 
 import pytest
@@ -107,7 +108,7 @@ def test_moe_shard_map_matches_gspmd():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, cfg.d_model))
     y0, m0 = moe_apply(params, cfg, x)
     cfg_sm = _cfg(ranking="sort", impl="shard_map")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with mesh, rules.activation_mesh(mesh):
         y1, m1 = jax.jit(lambda p, xx: moe_apply(p, cfg_sm, xx))(params, x)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y0),

@@ -13,6 +13,8 @@ from jax.sharding import PartitionSpec as P
 from repro import configs
 from repro.core import partitioner, tp
 from repro.core.bsr import BlockSparseMatrix
+from repro.launch.mesh import make_host_mesh, make_mesh
+from repro.launch.train import train_loop
 from repro.sharding import rules
 
 NDEV = len(jax.devices())
@@ -23,16 +25,12 @@ needs_mesh = pytest.mark.skipif(
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _amesh(shape, names=("data", "model")):
-    """Abstract mesh: rule tests need axis sizes, not real devices.
-    jax < 0.5 takes ((name, size), ...); newer takes (sizes, names)."""
-    try:
-        return AbstractMesh(shape, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, shape)))
+    """Abstract mesh: rule tests need axis sizes, not real devices."""
+    return AbstractMesh(shape, names)
 
 
 def _sizes(mesh):
@@ -100,11 +98,21 @@ def test_constrain_noop_without_mesh():
 
 
 def test_constrain_applies_under_mesh():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     x = jnp.ones((4, 4))
     with rules.activation_mesh(mesh):
         y = rules.constrain(x, "batch", "model")
     np.testing.assert_allclose(np.asarray(y), np.asarray(x))
+
+
+def test_train_loop_runs_on_host_mesh():
+    """The training entry point's default mesh (``make_host_mesh``)
+    accepts the sharding constraints of a real step: two steps of the
+    smoke config run to the end with finite losses."""
+    _, losses = train_loop(configs.smoke("qwen2_1_5b"), steps=2,
+                           batch_per_shard=2, seq=16, ckpt_dir=None,
+                           mesh=make_host_mesh(), log_every=100)
+    assert len(losses) == 2 and np.isfinite(losses).all()
 
 
 def test_train_batch_specs(mesh):
@@ -153,7 +161,7 @@ def test_tp_shard_map_vs_gspmd_parity(balanced, dtype, tol):
         assert widths.max() > widths.min()
     assert meta.balanced is balanced
     sb = partitioner.apply_k_shards(meta, bsr.values)
-    mesh = jax.make_mesh((q,), ("model",))
+    mesh = make_mesh((q,), ("model",))
     y_sm = tp.tp_spmm_shard_map(sb, x, mesh=mesh, axis="model")
     y_gs = tp.tp_spmm_gspmd(sb, x, axis="model")
     np.testing.assert_allclose(
@@ -174,7 +182,7 @@ def test_tp_shard_map_on_two_axis_mesh():
     x = jax.random.normal(jax.random.PRNGKey(3), (bsr.shape[1], 16))
     meta = partitioner.plan_k_shards(bsr, 4)
     sb = partitioner.apply_k_shards(meta, bsr.values)
-    mesh = jax.make_mesh((NDEV // 4, 4), ("data", "model"))
+    mesh = make_mesh((NDEV // 4, 4), ("data", "model"))
     y = tp.tp_spmm_shard_map(sb, x, mesh=mesh, axis="model")
     oracle = jnp.asarray(bsr.to_dense()) @ x
     np.testing.assert_allclose(np.asarray(y), np.asarray(oracle),
@@ -190,7 +198,7 @@ def test_tp_shard_map_rejects_mismatched_mesh():
     sb = partitioner.apply_k_shards(meta, bsr.values)
     with pytest.raises(ValueError, match="axis 'model'"):
         tp.tp_spmm_shard_map(sb, x, mesh=None, axis="model")
-    mesh1 = jax.make_mesh((1,), ("model",))
+    mesh1 = make_mesh((1,), ("model",))
     if mesh1.shape["model"] != sb.q:
         with pytest.raises(ValueError, match="size q=2"):
             tp.tp_spmm_shard_map(sb, x, mesh=mesh1, axis="model")

@@ -15,6 +15,7 @@ from repro import sparse
 from repro.core import dispatch, dynamic_sparse as dsp, \
     static_sparse as ssp
 from repro.core.bsr import BlockSparseMatrix
+from repro.launch.mesh import make_mesh
 
 M, K, N, B, DENSITY = 128, 256, 64, 16, 0.25
 
@@ -588,7 +589,7 @@ def test_mesh_without_tp_axis_raises():
     """Regression: a mesh whose axes do not include tp_axis used to
     silently plan unsharded; it must raise naming the expected axis."""
     bsr, _, _ = _problem()
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     with pytest.raises(ValueError, match=r"tp_axis 'model'"):
         sparse.plan(bsr, N, ctx=sparse.PlanContext(mesh=mesh))
     # naming the right axis (or an explicit tp_q) fixes it
@@ -619,7 +620,7 @@ def test_tp_measured_race_gspmd_vs_shardmap_vs_unsharded():
     lowerings against the unsharded candidates with wall-clock timings
     and surfaces the crossover."""
     bsr, x, oracle = _problem()
-    mesh = jax.make_mesh((NDEV,), ("model",))
+    mesh = make_mesh((NDEV,), ("model",))
     p = sparse.plan(bsr, N, x=x,
                     ctx=sparse.PlanContext(mesh=mesh, measure=True))
     assert p.source == "measured"
@@ -638,7 +639,7 @@ def test_tp_verdict_disk_round_trip_is_mesh_keyed(tmp_path):
     """A measured TP verdict persists, restarts re-plan with zero
     measurements, and a different mesh topology never reuses it."""
     bsr, x, _ = _problem()
-    mesh = jax.make_mesh((NDEV,), ("model",))
+    mesh = make_mesh((NDEV,), ("model",))
     ctx = sparse.PlanContext(mesh=mesh, measure=True,
                              cache_dir=str(tmp_path))
     p1 = sparse.plan(bsr, N, x=x, ctx=ctx)
@@ -651,7 +652,7 @@ def test_tp_verdict_disk_round_trip_is_mesh_keyed(tmp_path):
     assert p2.artifacts["tp"]["mesh"] == {"model": NDEV}
 
     # same devices arranged as a different topology -> different key
-    sub = jax.make_mesh((1, NDEV), ("data", "model"))
+    sub = make_mesh((1, NDEV), ("data", "model"))
     sparse.reset()
     p3 = sparse.plan(bsr, N, x=x,
                      ctx=dataclasses.replace(ctx, mesh=sub))
@@ -689,7 +690,7 @@ def test_tp_q_and_mesh_fingerprints_differ():
     plan_mod = importlib.import_module("repro.sparse.plan")
     spec = sparse.OpSpec.from_operand(bsr, N, mode="auto")
     fp_q = plan_mod._fingerprint(spec, sparse.PlanContext(tp_q=2))
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     fp_mesh = plan_mod._fingerprint(
         spec, sparse.PlanContext(mesh=mesh, tp_q=2))
     assert fp_q != fp_mesh
@@ -703,7 +704,7 @@ def test_tp_race_remeasures_stale_analytic_unsharded_verdict():
     against wall-clock TP timings (incomparable units)."""
     bsr1, x, _ = _problem(seed=0)
     bsr2 = _bsr(seed=7)                   # same shapes, fresh pattern
-    mesh = jax.make_mesh((NDEV,), ("model",))
+    mesh = make_mesh((NDEV,), ("model",))
     ctx = sparse.PlanContext(mesh=mesh, measure=True)
     p1 = sparse.plan(bsr1, N, ctx=ctx)    # no x -> analytic, cached
     assert p1.source == "analytic"
@@ -720,10 +721,7 @@ def test_abstract_mesh_plans_gspmd_only():
     warmup sees) must plan fine with the shard_map route excluded, not
     crash probing .devices."""
     from jax.sharding import AbstractMesh
-    try:
-        amesh = AbstractMesh((8,), ("model",))
-    except TypeError:                     # older jax signature
-        amesh = AbstractMesh((("model", 8),))
+    amesh = AbstractMesh((8,), ("model",))
     bsr, _, _ = _problem()
     ctx = sparse.PlanContext(mesh=amesh)
     assert not ctx.shardmap_executable()

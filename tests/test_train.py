@@ -10,6 +10,7 @@ import pytest
 from repro import configs
 from repro.checkpoint import latest_step, restore, save
 from repro.data import TokenPipeline
+from repro.launch.mesh import make_mesh
 from repro.launch.train import train_loop
 from repro.models.model import LM
 from repro.sharding import rules
@@ -88,7 +89,7 @@ def test_elastic_restore_across_mesh(tmp_path):
     params = lm.init(jax.random.PRNGKey(0))
     p = str(tmp_path)
     save(p, params, step=1)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     specs = rules.param_specs(jax.eval_shape(lambda: params), mesh)
     got, _, _ = restore(p, jax.eval_shape(lambda: params), mesh=mesh,
                         specs=specs)
