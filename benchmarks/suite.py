@@ -675,7 +675,7 @@ def serving_throughput(tiny: bool = False):
         for ffn, c in (("dense", cfg),
                        ("sparse_1_8", configs.sparse_ffn(cfg, 1 / 8))):
             shapes = _stack_shapes(c)
-            buckets = _auto_buckets(max_len - 1, shapes, 0.5)
+            buckets = _auto_buckets(max_len, shapes, 0.5)
             best = None
             sweep = {}
             for batch in (4, 8, 16, 32):
@@ -688,7 +688,7 @@ def serving_throughput(tiny: bool = False):
                     best = (batch, rps, p99, pad, prompt)
             batch, rps, p99, pad, prompt = best
             pm_total, _, _, _ = _serve_sim(
-                shapes, (max_len - 1,), lens, max_new, batch)
+                shapes, (max_len,), lens, max_new, batch)
             rec = dict(
                 fig="serving", model=name, ffn=ffn, max_len=max_len,
                 n_req=n_req, max_new=max_new,

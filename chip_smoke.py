@@ -8,12 +8,18 @@ One chip, three phases in one process:
 1. kernels -- every Pallas route of ``repro.sparse.plan`` forced once and
    compiled by Mosaic (never interpreted) at the qwen2-1.5b FFN
    up-projection (m=8960, k=1536, blocks of 16, density 1/8, bf16), at a
-   decode batch (n=8) and at the engine's top prefill bucket (n=1023),
-   each checked against a float32 ``jax.numpy`` product;
+   decode batch (n=8) and at an unaligned prefill length (n=1023, padded
+   inside the kernels), each checked against a float32 ``jax.numpy``
+   product;
 2. serving, dense FFN -- qwen2-1.5b at its published widths with random
-   weights, through ``serve.Engine`` (batch 8, max_len 1024): 8 requests,
-   16 new tokens each, no compile while serving, and the first request's
-   prefill logits checked against the same model planned ``dense_xla``;
+   weights, through ``serve.Engine`` (batch 8, max_len 1024): 8 requests
+   of 16 to 1008 prompt tokens, 16 new tokens each, no compile while
+   serving, the top bucket served; then checked against the same model
+   planned ``dense_xla``: prefill logits of the shortest and the longest
+   request at their buckets, of the longest at its exact length (token
+   axis padded in the kernels and in attention), of one request at its
+   exact length with an odd attention tile count under the balanced
+   schedule, and the logits of one decode step of the whole batch;
 3. serving, the paper's static block-sparse FFN (density 1/8, blocks of
    16) -- the same checks.
 
@@ -45,7 +51,7 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 # the bf16 budget of tests/conftest.py (GRAD_TOLS): max-norm relative
 BF16_BUDGET = 6e-2
 FFN = dict(m=8960, k=1536, b=16, density=0.125)     # qwen2-1.5b up-proj
-KERNEL_NS = (8, 1023)        # decode batch; top prefill bucket at 1024
+KERNEL_NS = (8, 1023)        # decode batch; an unaligned prefill length
 FORWARD_ROUTES = ("dense_pallas", "static_pallas", "static_balanced",
                   "dynamic_pallas", "dynamic_grouped",
                   "dynamic_grouped_balanced")
@@ -206,32 +212,64 @@ def serving_phase(cfg, counter, *, batch: int, max_len: int, requests: int,
               and all(0 <= t < cfg.vocab_size for t in r.output),
               f"request {r.uid}: {len(r.output)} tokens {r.output}")
     check(lowered == 0, f"{lowered} programs lowered while serving")
+    check(reqs[-1].bucket == eng.buckets[-1],
+          f"the longest prompt was served at bucket {reqs[-1].bucket}, "
+          f"not the top bucket {eng.buckets[-1]}")
 
-    # the engine's plans against the same model planned dense_xla
-    first = reqs[0]
-    L = eng.bucket_for(len(first.prompt))
-    tokens = np.zeros((1, L), np.int32)
-    tokens[0, :len(first.prompt)] = first.prompt
-    last = jnp.asarray([len(first.prompt) - 1], jnp.int32)
+    # the engine's plans against the same model planned dense_xla (and
+    # the row attention schedule), on identical inputs
+    ref_ctx = sparse.PlanContext(mode="dense_xla", differentiable=False)
 
-    def prefill_logits(ctx):
+    def prefill(prompt, L):
+        tokens = np.zeros((1, L), np.int32)
+        tokens[0, :len(prompt)] = prompt
+        last = np.asarray([len(prompt) - 1], np.int32)
+
+        def run(ctx, schedule):
+            with sparse.use_ctx(ctx):
+                return jax.jit(lambda p, t, i: lm.prefill(
+                    p, t, max_len=max_len, last_index=i,
+                    schedule=schedule)[0])(params, tokens, last)
+        return run
+
+    def decode(ctx, _):
+        # one step of the whole batch from the caches serving left
+        tokens = np.asarray([[r.output[-1]] for r in reqs], np.int32)
         with sparse.use_ctx(ctx):
-            return jax.jit(lambda p, t, i: lm.prefill(
-                p, t, max_len=max_len, last_index=i)[0])(params, tokens, last)
-    t0 = time.perf_counter()
-    built = sparse.cache_stats()["plans_built"]
-    got = prefill_logits(eng.plan_ctx)
-    check(sparse.cache_stats()["plans_built"] == built,
-          "the engine's context planned anew")
-    ref = prefill_logits(sparse.PlanContext(mode="dense_xla",
-                                            differentiable=False))
-    err = rel_err(got, ref)
-    print(f"  prefill logits (bucket {L}) vs dense_xla plans: rel err "
-          f"{err:.2e}, host wall-clock {time.perf_counter() - t0:.1f} s",
-          flush=True)
-    check(err <= BF16_BUDGET, f"prefill logits {err:.2e} over the bf16 "
-          f"budget {BF16_BUDGET}")
-    del eng, params, got, ref
+            return jax.jit(lambda p, t, c, i: lm.decode_step(
+                p, t, c, i)[0])(params, tokens, eng.caches,
+                                np.asarray(eng.positions))
+
+    def compare(what, run, served, schedule=None):
+        t0 = time.perf_counter()
+        built = sparse.cache_stats()["plans_built"]
+        got = run(eng.plan_ctx, schedule)
+        check(not served or sparse.cache_stats()["plans_built"] == built,
+              f"{what}: the engine's context planned anew")
+        err = rel_err(got, run(ref_ctx, None))
+        print(f"  {what} vs dense_xla plans: rel err {err:.2e}, host "
+              f"wall-clock {time.perf_counter() - t0:.1f} s", flush=True)
+        check(err <= BF16_BUDGET, f"{what}: {err:.2e} over the bf16 "
+              f"budget {BF16_BUDGET}")
+
+    for r in (reqs[0], reqs[-1]):
+        n, L = len(r.prompt), eng.bucket_for(len(r.prompt))
+        compare(f"prefill logits, {n} tokens at bucket {L}",
+                prefill(r.prompt, L), served=True)
+    # exact length: the token axis padded inside every kernel and in
+    # attention, where padded keys are masked
+    n = len(reqs[-1].prompt)
+    compare(f"prefill logits, {n} tokens at exact length",
+            prefill(reqs[-1].prompt, n), served=False)
+    # an odd number of attention tiles under the balanced schedule
+    tq = cfg.attn_tile_q
+    odd = next(r.prompt for r in reqs
+               if len(r.prompt) > tq and -(-len(r.prompt) // tq) % 2)
+    compare(f"prefill logits, {len(odd)} tokens at exact length, balanced "
+            f"attention schedule", prefill(odd, len(odd)), served=False,
+            schedule="balanced")
+    compare(f"decode logits, batch of {batch}", decode, served=True)
+    del eng, params
     sparse.reset()
     gc.collect()
     print(f"  released: {live_bytes() / 2**20:.0f} MiB of live arrays left",
@@ -270,13 +308,17 @@ def tp_phase(seed: int):
                     v4, x4).compile()
             y = compiled(v4, x4)
             err, vs_one = rel_err(y, want), rel_err(y, base)
+            reduced = "all-reduce" in compiled.as_text()
+            spread = len(y.sharding.device_set)
             print(f"  {route:26s} n={n:5d} rel err {err:.2e}, vs 1-chip "
                   f"static_pallas {vs_one:.2e}; all-reduce in program: "
-                  f"{'all-reduce' in compiled.as_text()}; output on "
-                  f"{len(y.sharding.device_set)} devices", flush=True)
+                  f"{reduced}; output on {spread} devices", flush=True)
             check(err <= BF16_BUDGET and vs_one <= BF16_BUDGET,
                   f"{route} n={n}: {err:.2e} / {vs_one:.2e} over the bf16 "
                   f"budget {BF16_BUDGET}")
+            # a route that quietly ran replicated would match numerically
+            check(reduced and spread == 4, f"{route} n={n}: all-reduce "
+                  f"{reduced}, output on {spread} devices; not sharded")
 
 
 def main(argv=None):
@@ -309,11 +351,11 @@ def main(argv=None):
         with Phase("kernels", counter, label):
             kernel_phase(args.seed)
         dense = configs.get("qwen2_1_5b")
-        for cfg in (dense, configs.sparse_ffn(dense, 0.125, 16)):
+        for cfg in (dense, configs.sparse_ffn(dense, 0.125)):
             name = f"serve-{cfg.groups[0][0][0].ffn}"
             with Phase(name, counter, label):
                 serving_phase(cfg, counter, batch=8, max_len=1024,
-                              requests=8, lens=(16, 900), new_tokens=16,
+                              requests=8, lens=(16, 1008), new_tokens=16,
                               seed=args.seed)
     print(json.dumps({"ok": True, "device": {
         "platform": d.platform, "kind": d.device_kind, "count": len(devs)}}))

@@ -74,17 +74,14 @@ def smoke(name: str) -> ModelCfg:
     return _module(name).make_smoke_config()
 
 
-def sparse_ffn(cfg: ModelCfg, density: float,
-               block_size: int | None = None) -> ModelCfg:
+def sparse_ffn(cfg: ModelCfg, density: float) -> ModelCfg:
     """``cfg`` with every FFN swapped for the paper's static block-sparse
-    FFN at ``density`` (blocks of ``block_size``, default the config's
-    own ``ffn_block_size``); every other width is unchanged."""
+    FFN at ``density`` (blocks of the config's ``ffn_block_size``);
+    every other width is unchanged."""
     groups = tuple(
         (tuple(dataclasses.replace(s, ffn="sparse") for s in period), rep)
         for period, rep in cfg.groups)
-    return dataclasses.replace(
-        cfg, groups=groups, ffn_density=density,
-        ffn_block_size=block_size or cfg.ffn_block_size)
+    return dataclasses.replace(cfg, groups=groups, ffn_density=density)
 
 
 def is_native_long(cfg: ModelCfg) -> bool:

@@ -3,7 +3,7 @@
 Mosaic accepts a block whose last two dimensions are multiples of
 ``(8, 128)``, or that spans the whole array dimension.  The wrappers
 therefore never shrink a tile below that alignment to make it divide an
-awkward length (a prefill bucket of 1023 tokens once got a tile of 1):
+awkward length (a prefill of 1023 tokens once got a tile of 1):
 a dimension is either taken whole, or zero-padded up to a multiple of
 an aligned tile and the result sliced back.  Zero rows and columns add
 nothing to a contraction, so padding never changes a result.
@@ -16,13 +16,13 @@ LANE = 128      # minor-dimension alignment of a block
 SUBLANE = 8     # second-minor alignment of a block
 
 
-def dim_tile(n: int, pref: int = LANE) -> tuple[int, int]:
+def dim_tile(n: int) -> tuple[int, int]:
     """``(tile, padded_n)`` for a dimension of length ``n``: one whole
-    block when ``n <= pref``, else ``pref``-wide tiles over ``n``
-    rounded up to a multiple of ``pref``."""
-    if n <= pref:
+    block when ``n <= LANE``, else ``LANE``-wide tiles over ``n``
+    rounded up to a multiple of ``LANE``."""
+    if n <= LANE:
         return n, n
-    return pref, -(-n // pref) * pref
+    return LANE, -(-n // LANE) * LANE
 
 
 def pad_to(x, axis: int, size: int):

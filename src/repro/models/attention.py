@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import masks as masks_lib
+from repro.kernels.tiling import LANE
 from repro.models.layers import apply_rope, dense, dense_init, rms_norm
 from repro.sharding.rules import constrain
 
@@ -234,10 +235,9 @@ def _attend_balanced_causal(q, k, v, *, scale, softcap, tile_q, tile_kv,
     outs = outs.reshape(-1, b_, h, tile_q, dh)      # [2*nsteps, ...]
     # static inverse permutation: row r was emitted at flat slot inv[r]
     flat_rows = sched.rows.reshape(-1)
-    inv = np.zeros(nq, np.int64)
     # an odd nq lists its middle row twice and feeds only the first slot,
-    # so the first occurrence must win
-    inv[flat_rows[::-1]] = np.arange(flat_rows.shape[0])[::-1]
+    # so each row's first occurrence is its slot
+    _, inv = np.unique(flat_rows, return_index=True)
     outs = outs[jnp.asarray(inv)]
     return outs.transpose(1, 0, 3, 2, 4).reshape(b_, s, h, dh)
 
@@ -338,14 +338,14 @@ def _repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
                             ).reshape(b_, s, kv * n_rep, dh)
 
 
-def _seq_tile(n: int, pref: int, floor: int = 128) -> tuple[int, int]:
+def _seq_tile(n: int, pref: int) -> tuple[int, int]:
     """``(tile, padded_n)`` for a sequence axis of length ``n``: the
     whole axis up to ``pref``; above it ``pref`` halved while it does not
-    divide ``n``, but never below ``floor`` -- the axis is zero-padded to
+    divide ``n``, but never below ``LANE`` -- the axis is zero-padded to
     a multiple of the tile instead (halving an odd prefill length down
     to a divisor gave one-token tiles and a scan of ``n**2 / 2`` steps)."""
     t = min(pref, n)
-    while n % t and t // 2 >= floor:
+    while n % t and t // 2 >= LANE:
         t //= 2
     return t, -(-n // t) * t
 

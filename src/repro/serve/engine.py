@@ -9,8 +9,9 @@ running end to end:
   prefill compiles once per *bucket*, not once per prompt length.  The
   bucket ladder is chosen analytically at startup by the calibrated
   cost model (``dispatch.price_tokens`` over the model's matmul stack):
-  buckets grow geometrically until the priced padding waste of the
-  worst-padded prompt would exceed ``pad_max_frac``.  Padding is
+  buckets grow, over the token counts the kernels tile without padding,
+  until the priced padding waste of the worst-padded prompt would
+  exceed ``pad_max_frac``.  Padding is
   correct because logits are gathered at the *true* last prompt token
   (``LM.prefill(last_index=...)``) and decode attention masks cache
   slots beyond each slot's true position; SSM/hybrid stacks carry
@@ -60,6 +61,7 @@ import numpy as np
 
 from repro import sparse as sparse_api
 from repro.core import dispatch
+from repro.kernels.tiling import dim_tile
 from repro.models.config import ModelCfg
 from repro.models.model import LM
 
@@ -130,16 +132,18 @@ def _stack_shapes(cfg: ModelCfg) -> List[Tuple[int, int]]:
 def _auto_buckets(top: int, shapes: Sequence[Tuple[int, int]],
                   pad_max_frac: float, *,
                   granularity: int = 16) -> Tuple[int, ...]:
-    """Analytic bucket ladder: starting from the smallest bucket, each
-    next bucket is the largest size whose *priced* padding waste for
-    the worst-padded prompt (one token past the previous bucket) stays
-    under ``pad_max_frac`` -- cost-model geometry instead of blind
-    powers of two, so fixed per-call overheads (which make short
-    prefills cheap to pad) widen the small buckets and the ladder stays
-    short.  Always ends at ``top`` (= max_len - 1, the longest
-    admissible prompt)."""
-    if top <= granularity:
-        return (top,)
+    """Analytic bucket ladder over the sizes the kernels run without
+    padding (``tiling.dim_tile``): multiples of ``granularity`` up to
+    one lane tile, multiples of the lane tile above it.  Starting from
+    the smallest, each next bucket is the largest such size whose
+    *priced* padding waste for the worst-padded prompt (one token past
+    the previous bucket) stays under ``pad_max_frac`` -- cost-model
+    geometry instead of blind powers of two, so fixed per-call overheads
+    (which make short prefills cheap to pad) widen the small buckets and
+    the ladder stays short.  Always ends at ``top`` (= max_len, the
+    cache length)."""
+    sizes = [n for n in range(granularity, top, granularity)
+             if dim_tile(n)[1] == n] + [top]
     price = {}
 
     def _p(n: int) -> float:
@@ -147,17 +151,15 @@ def _auto_buckets(top: int, shapes: Sequence[Tuple[int, int]],
             price[n] = dispatch.price_tokens(shapes, n)
         return price[n]
 
-    buckets = [granularity]
-    while buckets[-1] < top:
+    i = 0
+    buckets = [sizes[0]]
+    while i + 1 < len(sizes):
         lo = buckets[-1]
-        nxt = min(lo + granularity, top)
-        cand = nxt + granularity
-        while cand <= top:
-            if 1.0 - _p(lo + 1) / _p(cand) > pad_max_frac:
-                break
-            nxt = cand
-            cand += granularity
-        buckets.append(nxt)
+        i += 1
+        while (i + 1 < len(sizes)
+               and 1.0 - _p(lo + 1) / _p(sizes[i + 1]) <= pad_max_frac):
+            i += 1
+        buckets.append(sizes[i])
     return tuple(buckets)
 
 
@@ -226,7 +228,9 @@ class Engine:
         self.pad_max_frac = float(pad_max_frac)
         self._shapes = _stack_shapes(lm.cfg)
         self.pad_safe = _pad_safe(lm.cfg)
-        top = max_len - 1
+        # the top bucket fills the whole cache row: a prompt is at most
+        # max_len - 1 tokens, and the padded tail is never attended
+        top = max_len
         if not self.pad_safe:
             self.buckets: Tuple[int, ...] = ()   # exact-length prefill
         elif buckets is not None:

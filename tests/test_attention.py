@@ -76,7 +76,7 @@ def test_attend_train_softcap_noncausal():
                          [(True, "row", 0), (True, "balanced", 0),
                           (True, "row", 100), (False, "row", 0)])
 def test_attend_train_unaligned_length(causal, schedule, window):
-    """An odd length (an engine's ``max_len - 1`` prefill bucket) keeps
+    """An odd length (an exact-length prefill) keeps
     128-row tiles over a zero-padded axis -- halving to a divisor would
     give one-token tiles -- and padded keys are never attended."""
     b, s, h, dh = 1, 383, 2, 32

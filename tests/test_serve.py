@@ -17,6 +17,7 @@ import pytest
 
 from repro import configs, sparse as sparse_api
 from repro.core.bsr import BlockSparseMatrix
+from repro.kernels.tiling import dim_tile
 from repro.models.model import LM
 from repro.serve import Engine, Request
 from repro.serve.engine import _auto_buckets, _pad_safe, _stack_shapes
@@ -173,7 +174,7 @@ def test_on_finish_fires_exactly_once_per_request():
 
 def test_prefill_compiles_once_per_bucket_not_per_length():
     eng = _stub_engine(batch=2, max_len=20, buckets=(4, 8, 16))
-    assert eng.buckets == (4, 8, 16, 19)
+    assert eng.buckets == (4, 8, 16, 20)
     lengths = [2, 3, 4, 5, 7, 9, 11, 15]     # 8 lengths, 3 buckets
     reqs = [_req(np.arange(s) % V, uid=i, max_new_tokens=2)
             for i, s in enumerate(lengths)]
@@ -326,6 +327,8 @@ def test_auto_buckets_cover_and_end_at_top():
         ladder = _auto_buckets(511, shapes, frac)
         assert ladder[-1] == 511
         assert list(ladder) == sorted(set(ladder))
+        # below the top, only lengths the kernels tile without padding
+        assert all(dim_tile(b)[1] == b for b in ladder[:-1])
     # tighter waste budget => at least as many buckets
     assert len(_auto_buckets(511, shapes, 0.25)) >= \
         len(_auto_buckets(511, shapes, 0.75))

@@ -4,8 +4,8 @@ by Mosaic for a described TPU v5e at qwen2-1.5b FFN widths.
 Interpret mode cannot see Mosaic's tiling and fast-memory rules, so
 these tests compile (nothing runs) each kernel through its ``ops``
 wrapper -- not through ``dispatch``, which asks ``default_backend()``
-and sees the CPU here -- at decode ``n=8`` and at the unaligned top
-prefill bucket ``n=1023`` of an engine with ``max_len=1024`` (and
+and sees the CPU here -- at decode ``n=8`` and at an unaligned
+exact-length prefill ``n=1023`` (and
 ``bs_attn``, which has no route yet, at one 1024-token prefill).  The
 topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler library.

@@ -57,8 +57,9 @@ _R001_ALLOWED_FILES = {
     "tests/test_gmm_capacity.py",     # grouped-kernel capacity tests
     "tests/test_tpu_compile.py",      # Mosaic compiles of the wrappers
 }
-# the contract is kernel *metadata*, not a kernel entry point
-_R001_EXEMPT_MODULES = ("repro.kernels.contract",)
+# the contract and the tile alignment are kernel *metadata*, not kernel
+# entry points
+_R001_EXEMPT_MODULES = ("repro.kernels.contract", "repro.kernels.tiling")
 
 
 @register_rule
