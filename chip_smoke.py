@@ -175,6 +175,7 @@ def live_bytes() -> int:
 def serving_phase(cfg, counter, *, batch: int, max_len: int, requests: int,
                   lens: tuple, new_tokens: int, seed: int):
     from repro import sparse
+    from repro.models.attention import _seq_tile
     from repro.models.model import LM
     from repro.serve import Engine, Request
     sparse.reset()
@@ -262,9 +263,12 @@ def serving_phase(cfg, counter, *, batch: int, max_len: int, requests: int,
     compare(f"prefill logits, {n} tokens at exact length",
             prefill(reqs[-1].prompt, n), served=False)
     # an odd number of attention tiles under the balanced schedule
-    tq = cfg.attn_tile_q
+    def attn_tiles(n):
+        tile, padded = _seq_tile(n, cfg.attn_tile_q)
+        return padded // tile
     odd = next(r.prompt for r in reqs
-               if len(r.prompt) > tq and -(-len(r.prompt) // tq) % 2)
+               if attn_tiles(len(r.prompt)) % 2
+               and attn_tiles(len(r.prompt)) > 1)
     compare(f"prefill logits, {len(odd)} tokens at exact length, balanced "
             f"attention schedule", prefill(odd, len(odd)), served=False,
             schedule="balanced")
