@@ -144,11 +144,13 @@ def pack_values(plan: PackingPlan, values) -> jax.Array:
     Jit-compatible (metadata is host constants)."""
     b = plan.block_size
     rpb, cpb = plan.tm // b, plan.tk // b
-    vals = jnp.asarray(values)
-    tiles = jnp.zeros((plan.num_tiles, rpb, b, cpb, b), vals.dtype)
-    tiles = tiles.at[jnp.asarray(plan.block_slot), jnp.asarray(plan.in_r),
-                     :, jnp.asarray(plan.in_c), :].add(vals)
-    return tiles.reshape(plan.num_tiles, plan.tm, plan.tk)
+    with jax.named_scope("pack_values"):
+        vals = jnp.asarray(values)
+        tiles = jnp.zeros((plan.num_tiles, rpb, b, cpb, b), vals.dtype)
+        tiles = tiles.at[jnp.asarray(plan.block_slot),
+                         jnp.asarray(plan.in_r), :,
+                         jnp.asarray(plan.in_c), :].add(vals)
+        return tiles.reshape(plan.num_tiles, plan.tm, plan.tk)
 
 
 @dataclasses.dataclass(frozen=True)

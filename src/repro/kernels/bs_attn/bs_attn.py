@@ -106,4 +106,5 @@ def bs_attn_call(tile_rows, tile_cols, q, k, v, *, bq: int, bkv: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="bs_attn_call",
     )(tile_rows, tile_cols, q, k, v)

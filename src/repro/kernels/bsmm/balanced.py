@@ -93,4 +93,5 @@ def bsmm_balanced_call(visit_rows, visit_cols, visit_slot, tiles, x, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="bsmm_balanced_call",
     )(visit_rows, visit_cols, visit_slot, tiles, x)

@@ -79,4 +79,5 @@ def bsmm_call(tile_rows, tile_cols, tiles, x, *, tm: int, tk: int, tn: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="bsmm_call",
     )(tile_rows, tile_cols, tiles, x)

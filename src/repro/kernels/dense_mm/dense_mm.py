@@ -52,4 +52,5 @@ def dense_mm_call(a, b, *, tm: int, tk: int, tn: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="dense_mm_call",
     )(a, b)

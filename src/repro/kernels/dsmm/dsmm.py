@@ -81,4 +81,5 @@ def dsmm_call(rows, cols, values, x, *, b: int, tn: int, grid_m: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="dsmm_call",
     )(rows, cols, values, x)

@@ -61,4 +61,5 @@ def gmm_call(expert_ids, x, w, *, tm: int, tf: int, td: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="gmm_call",
     )(expert_ids, x, w)

@@ -83,4 +83,5 @@ def sddmm_tiles_call(tile_rows, tile_cols, dy, x, *, t: int, tn: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="sddmm_tiles_call",
     )(tile_rows, tile_cols, dy, x)

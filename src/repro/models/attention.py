@@ -488,8 +488,9 @@ def gqa_decode(params, cfg, x, cache, *, positions, slot=None,
     q, k_new, v_new = _project_qkv(params, cfg, x, positions[:, None])
     slot = positions if slot is None else slot
     bidx = jnp.arange(x.shape[0])
-    k_cache = cache["k"].at[bidx, slot].set(k_new[:, 0])
-    v_cache = cache["v"].at[bidx, slot].set(v_new[:, 0])
+    with jax.named_scope("kv_update"):
+        k_cache = cache["k"].at[bidx, slot].set(k_new[:, 0])
+        v_cache = cache["v"].at[bidx, slot].set(v_new[:, 0])
     lengths = jnp.minimum(positions + 1, k_cache.shape[1])
     scale = cfg.attn_scale or 1.0 / np.sqrt(cfg.head_dim)
     use_win = local and window_filter
@@ -523,8 +524,9 @@ def gqa_prefill(params, cfg, x, *, positions, max_len: int,
     b_, s = x.shape[:2]
     y = dense(params["wo"], out.reshape(b_, s, -1))
     pad = [(0, 0), (0, max_len - s), (0, 0), (0, 0)]
-    cache = {"k": jnp.pad(k, pad).astype(x.dtype),
-             "v": jnp.pad(v, pad).astype(x.dtype)}
+    with jax.named_scope("kv_update"):
+        cache = {"k": jnp.pad(k, pad).astype(x.dtype),
+                 "v": jnp.pad(v, pad).astype(x.dtype)}
     return y, cache
 
 
@@ -655,8 +657,10 @@ def mla_prefill(params, cfg, x, *, positions, max_len: int,
     latent, k_rope = _mla_kv(params, cfg, x)
     k_rope = apply_rope(k_rope, positions, theta=cfg.rope_theta)
     pad2 = [(0, 0), (0, max_len - s), (0, 0)]
-    cache = {"latent": jnp.pad(latent, pad2).astype(x.dtype),
-             "k_rope": jnp.pad(k_rope[:, :, 0, :], pad2).astype(x.dtype)}
+    with jax.named_scope("kv_update"):
+        cache = {"latent": jnp.pad(latent, pad2).astype(x.dtype),
+                 "k_rope": jnp.pad(k_rope[:, :, 0, :], pad2).astype(
+                     x.dtype)}
     return y, cache
 
 
@@ -670,8 +674,10 @@ def mla_decode(params, cfg, x, cache, *, positions, slot=None):
                             theta=cfg.rope_theta)
     bidx = jnp.arange(b_)
     slot = positions if slot is None else slot
-    latent_c = cache["latent"].at[bidx, slot].set(latent_new[:, 0])
-    k_rope_c = cache["k_rope"].at[bidx, slot].set(k_rope_new[:, 0, 0])
+    with jax.named_scope("kv_update"):
+        latent_c = cache["latent"].at[bidx, slot].set(latent_new[:, 0])
+        k_rope_c = cache["k_rope"].at[bidx, slot].set(
+            k_rope_new[:, 0, 0])
     s = latent_c.shape[1]
     lengths = jnp.minimum(positions + 1, s)
 

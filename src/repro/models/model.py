@@ -75,15 +75,17 @@ class LM:
     # -- shared plumbing -----------------------------------------------------
     def _embed_tokens(self, params, tokens):
         cfg = self.cfg
-        h = embed(params["embed"], tokens)
-        if cfg.embed_scale:
-            h = h * jnp.asarray(np.sqrt(cfg.d_model), h.dtype)
+        with jax.named_scope("embed"):
+            h = embed(params["embed"], tokens)
+            if cfg.embed_scale:
+                h = h * jnp.asarray(np.sqrt(cfg.d_model), h.dtype)
         return h
 
     def _unembed(self, params, h):
         cfg = self.cfg
         table = params["lm_head" if "lm_head" in params else "embed"]
-        return unembed(table, h, softcap=cfg.final_softcap)
+        with jax.named_scope("unembed"):
+            return unembed(table, h, softcap=cfg.final_softcap)
 
     def _encode(self, params, enc_frames):
         """Bidirectional encoder over precomputed frame embeddings."""

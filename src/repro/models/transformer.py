@@ -11,6 +11,7 @@ configurable policy (cfg.remat); "full" recomputes everything (baseline),
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Dict
 
@@ -78,25 +79,34 @@ def _sparse_ffn(cfg: ModelCfg):
                               cfg.dtype)
 
 
+def _mixer_scope(spec: LayerSpec):
+    """``attn`` around an attention mixer's call (projections, rope, KV
+    write, attention); the recurrent mixer stays unscoped."""
+    if spec.mixer == "mamba":
+        return contextlib.nullcontext()
+    return jax.named_scope("attn")
+
+
 def _apply_ffn(params, cfg, spec, h):
     metrics = _zero_metrics()
     if spec.ffn == "none":
         return jnp.zeros_like(h), metrics
-    hn = rms_norm(params["norm2"], h, eps=cfg.norm_eps,
-                  plus_one=cfg.post_norm)
-    if spec.ffn == "mlp":
-        out = mlp(params["ffn"], hn, act=cfg.act)
-    elif spec.ffn == "moe":
-        out, m = moe_lib.moe_apply(params["ffn"], cfg, hn)
-        metrics = {"aux_loss": m.aux_loss, "z_loss": m.z_loss,
-                   "dropped_frac": m.dropped_frac}
-    elif spec.ffn == "sparse":
-        out = _sparse_ffn(cfg).apply(params["ffn"], hn)
-    else:
-        raise ValueError(spec.ffn)
-    if cfg.post_norm:
-        out = rms_norm(params["post_norm2"], out, eps=cfg.norm_eps,
-                       plus_one=True)
+    with jax.named_scope("ffn"):
+        hn = rms_norm(params["norm2"], h, eps=cfg.norm_eps,
+                      plus_one=cfg.post_norm)
+        if spec.ffn == "mlp":
+            out = mlp(params["ffn"], hn, act=cfg.act)
+        elif spec.ffn == "moe":
+            out, m = moe_lib.moe_apply(params["ffn"], cfg, hn)
+            metrics = {"aux_loss": m.aux_loss, "z_loss": m.z_loss,
+                       "dropped_frac": m.dropped_frac}
+        elif spec.ffn == "sparse":
+            out = _sparse_ffn(cfg).apply(params["ffn"], hn)
+        else:
+            raise ValueError(spec.ffn)
+        if cfg.post_norm:
+            out = rms_norm(params["post_norm2"], out, eps=cfg.norm_eps,
+                           plus_one=True)
     return out, metrics
 
 
@@ -109,15 +119,17 @@ def layer_apply(params, cfg: ModelCfg, spec: LayerSpec, h, *, positions,
     hn = rms_norm(params["norm1"], h, eps=cfg.norm_eps,
                   plus_one=cfg.post_norm)
     sched = schedule or cfg.attn_schedule
-    if spec.mixer in ("attn", "attn_local"):
-        mix = attn.gqa_train(params["attn"], cfg, hn, positions=positions,
-                             local=spec.mixer == "attn_local",
-                             causal=spec.causal, schedule=sched)
-    elif spec.mixer == "mla":
-        mix = attn.mla_train(params["attn"], cfg, hn, positions=positions,
-                             schedule=sched)
-    else:
-        mix = ssm_lib.ssm_train(params["mixer"], cfg, hn)
+    with _mixer_scope(spec):
+        if spec.mixer in ("attn", "attn_local"):
+            mix = attn.gqa_train(params["attn"], cfg, hn,
+                                 positions=positions,
+                                 local=spec.mixer == "attn_local",
+                                 causal=spec.causal, schedule=sched)
+        elif spec.mixer == "mla":
+            mix = attn.mla_train(params["attn"], cfg, hn,
+                                 positions=positions, schedule=sched)
+        else:
+            mix = ssm_lib.ssm_train(params["mixer"], cfg, hn)
     if cfg.post_norm:
         mix = rms_norm(params["post_norm1"], mix, eps=cfg.norm_eps,
                        plus_one=True)
@@ -153,17 +165,19 @@ def layer_prefill(params, cfg: ModelCfg, spec: LayerSpec, h, *, positions,
     hn = rms_norm(params["norm1"], h, eps=cfg.norm_eps,
                   plus_one=cfg.post_norm)
     sched = schedule or cfg.attn_schedule
-    if spec.mixer in ("attn", "attn_local"):
-        mix, cache = attn.gqa_prefill(params["attn"], cfg, hn,
-                                      positions=positions, max_len=max_len,
-                                      local=spec.mixer == "attn_local",
-                                      schedule=sched)
-    elif spec.mixer == "mla":
-        mix, cache = attn.mla_prefill(params["attn"], cfg, hn,
-                                      positions=positions, max_len=max_len,
-                                      schedule=sched)
-    else:
-        mix, cache = ssm_lib.ssm_prefill(params["mixer"], cfg, hn)
+    with _mixer_scope(spec):
+        if spec.mixer in ("attn", "attn_local"):
+            mix, cache = attn.gqa_prefill(params["attn"], cfg, hn,
+                                          positions=positions,
+                                          max_len=max_len,
+                                          local=spec.mixer == "attn_local",
+                                          schedule=sched)
+        elif spec.mixer == "mla":
+            mix, cache = attn.mla_prefill(params["attn"], cfg, hn,
+                                          positions=positions,
+                                          max_len=max_len, schedule=sched)
+        else:
+            mix, cache = ssm_lib.ssm_prefill(params["mixer"], cfg, hn)
     if cfg.post_norm:
         mix = rms_norm(params["post_norm1"], mix, eps=cfg.norm_eps,
                        plus_one=True)
@@ -182,16 +196,18 @@ def layer_decode(params, cfg: ModelCfg, spec: LayerSpec, h, cache, *,
                  positions, slot=None, window_filter: bool = True):
     hn = rms_norm(params["norm1"], h, eps=cfg.norm_eps,
                   plus_one=cfg.post_norm)
-    if spec.mixer in ("attn", "attn_local"):
-        mix, cache = attn.gqa_decode(params["attn"], cfg, hn, cache,
-                                     positions=positions, slot=slot,
-                                     local=spec.mixer == "attn_local",
-                                     window_filter=window_filter)
-    elif spec.mixer == "mla":
-        mix, cache = attn.mla_decode(params["attn"], cfg, hn, cache,
-                                     positions=positions, slot=slot)
-    else:
-        mix, cache = ssm_lib.ssm_decode(params["mixer"], cfg, hn, cache)
+    with _mixer_scope(spec):
+        if spec.mixer in ("attn", "attn_local"):
+            mix, cache = attn.gqa_decode(params["attn"], cfg, hn, cache,
+                                         positions=positions, slot=slot,
+                                         local=spec.mixer == "attn_local",
+                                         window_filter=window_filter)
+        elif spec.mixer == "mla":
+            mix, cache = attn.mla_decode(params["attn"], cfg, hn, cache,
+                                         positions=positions, slot=slot)
+        else:
+            mix, cache = ssm_lib.ssm_decode(params["mixer"], cfg, hn,
+                                            cache)
     if cfg.post_norm:
         mix = rms_norm(params["post_norm1"], mix, eps=cfg.norm_eps,
                        plus_one=True)

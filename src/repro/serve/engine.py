@@ -71,6 +71,10 @@ _ENGINE_SEQ = itertools.count()
 
 _LATENCY_WINDOW = 2048          # rolling percentile window (per stream)
 
+# host spans on the profiler's clock (no-ops unless a trace is running);
+# ``docs/api.md`` lists them
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclasses.dataclass
 class Request:
@@ -484,21 +488,30 @@ class Engine:
         self._validate(req)
         if not self.free:
             return False
-        slot = self.free.pop()
         prompt = np.asarray(req.prompt, np.int32).reshape(-1)
         n = prompt.shape[0]
-        bucket = self.bucket_for(n)
-        if bucket is None:
-            padded = prompt[None, :]
-        else:
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :n] = prompt
-        t0 = time.perf_counter()
-        logits, row_caches = self._prefill(
-            self.params, padded, np.asarray([n - 1], np.int32))
-        tok = int(np.asarray(logits)[0].argmax())
+        with _span("engine.admit", uid=req.uid, prompt_len=n) as span:
+            bucket = self.bucket_for(n)
+            span.set_metadata(bucket="exact" if bucket is None else bucket)
+            return self._admit(req, self.free.pop(), prompt, bucket)
+
+    def _admit(self, req: Request, slot: int, prompt: np.ndarray,
+               bucket: Optional[int]) -> bool:
+        n = prompt.shape[0]
+        with _span("engine.admit.prefill"):
+            if bucket is None:
+                padded = prompt[None, :]
+            else:
+                padded = np.zeros((1, bucket), np.int32)
+                padded[0, :n] = prompt
+            t0 = time.perf_counter()
+            logits, row_caches = self._prefill(
+                self.params, padded, np.asarray([n - 1], np.int32))
+        with _span("engine.admit.readback"):
+            tok = int(np.asarray(logits)[0].argmax())
         dt = time.perf_counter() - t0
-        self.caches = self._write_slot(self.caches, row_caches, slot)
+        with _span("engine.admit.write_slot"):
+            self.caches = self._write_slot(self.caches, row_caches, slot)
         self.positions[slot] = n
         req.output.append(tok)
         req.bucket = bucket
@@ -534,14 +547,28 @@ class Engine:
         caller ever rescans the full request list."""
         if not self.live:
             return []
+        with _span("engine.step", step=self._steps, live=len(self.live)):
+            return self._step()
+
+    def _step(self) -> List[Request]:
         t0 = time.perf_counter()
-        tokens = np.zeros((self.batch, 1), np.int32)
-        for slot, req in self.live.items():
-            tokens[slot, 0] = req.output[-1]
-        logits, self.caches = self._decode(
-            self.params, jnp.asarray(tokens), self.caches,
-            jnp.asarray(self.positions))
-        nxt = self._next_tokens(logits)
+        with _span("engine.step.feed"):
+            tokens = np.zeros((self.batch, 1), np.int32)
+            for slot, req in self.live.items():
+                tokens[slot, 0] = req.output[-1]
+            tokens, positions = jnp.asarray(tokens), jnp.asarray(
+                self.positions)
+        with _span("engine.step.launch"):
+            logits, self.caches = self._decode(
+                self.params, tokens, self.caches, positions)
+        with _span("engine.step.readback"):
+            nxt = self._next_tokens(logits)
+        with _span("engine.step.retire"):
+            return self._retire(nxt, t0)
+
+    def _retire(self, nxt: np.ndarray, t0: float) -> List[Request]:
+        """Append each live slot's token and free the slots whose
+        request finished."""
         finished: List[Request] = []
         released: List[int] = []
         for slot, req in self.live.items():

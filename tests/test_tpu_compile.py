@@ -12,6 +12,7 @@ process at a time may load the TPU compiler library.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -53,10 +54,22 @@ def _pattern(m, k, b=BLOCK):
     return rows.astype(np.int32), cols.astype(np.int32)
 
 
-def _compile(fn, *shapes, sharding):
+_CUSTOM_CALL = re.compile(r"%([a-z_]+)\.\d+ = .*custom-call\(.*"
+                          r'custom_call_target="tpu_custom_call"'
+                          r'.*op_name="([^"]*)"')
+
+
+def _compile(fn, *shapes, sharding, kernels):
+    """Compile for the chip; every Mosaic kernel in the program is one
+    of ``kernels``, named after its jitted wrapper both as an operation
+    (what a profiler trace shows) and in its ``op_name`` (the kernel's
+    ``pallas_call(name=...)``)."""
     sds = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = jax.jit(fn).lower(*sds).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    calls = _CUSTOM_CALL.findall(compiled.as_text())
+    assert {name for name, _ in calls} == set(kernels)
+    for name, op_name in calls:
+        assert f"/{name}/pallas_call" in op_name, op_name
 
 
 def _dynamic(m, k, b, values, rows, cols, nnz):
@@ -69,7 +82,7 @@ def test_dense_mm_compiles(one_chip, proj, n):
     m, k = PROJ[proj]
     # activation-major, as the serving engine's dense layers call it
     _compile(dmm_ops.dense_mm, ((n, k), BF16), ((k, m), BF16),
-             sharding=one_chip)
+             sharding=one_chip, kernels=["dense_mm_call"])
 
 
 @pytest.mark.parametrize("n", [8, 1023])
@@ -87,7 +100,8 @@ def test_bsmm_compiles(one_chip, balanced, proj, n):
         meta = partitioner.plan_packing(rows, cols, (m, k), BLOCK, tm, tk)
         fn = functools.partial(bsmm_ops.bsmm_from_plan, meta)
     _compile(fn, ((len(rows), BLOCK, BLOCK), BF16), ((k, n), BF16),
-             sharding=one_chip)
+             sharding=one_chip,
+             kernels=["bsmm_balanced_call" if balanced else "bsmm_call"])
 
 
 @pytest.mark.parametrize("n", [8, 1023])
@@ -109,8 +123,16 @@ def test_dynamic_walks_compile(one_chip, kernel, n):
         return run(_dynamic(m, k, BLOCK, values, rows, cols, nnz_), x)
     _compile(fn, ((nnz, BLOCK, BLOCK), BF16), ((nnz,), jnp.int32),
              ((nnz,), jnp.int32), ((), jnp.int32), ((k, n), BF16),
-             sharding=one_chip)
+             sharding=one_chip, kernels=["dsmm_call"])
 
+
+
+def test_gmm_compiles(one_chip):
+    """The grouped GEMM of the MoE route: two experts at qwen2-1.5b's FFN
+    widths, one expert per 128-row tile."""
+    _compile(functools.partial(gmm_ops.gmm, tm=128),
+             ((256, D_MODEL), BF16), ((2, D_MODEL, D_FF), BF16),
+             ((2,), jnp.int32), sharding=one_chip, kernels=["gmm_call"])
 
 @pytest.mark.parametrize("n", [8, 1023])
 def test_sddmm_compiles(one_chip, n):
@@ -119,7 +141,8 @@ def test_sddmm_compiles(one_chip, n):
     t = sddmm_ops.sddmm_tile_size(m, k, BLOCK)
     meta = partitioner.plan_packing(rows, cols, (m, k), BLOCK, t, t)
     _compile(functools.partial(sddmm_ops.grouped_sddmm, meta),
-             ((m, n), BF16), ((k, n), BF16), sharding=one_chip)
+             ((m, n), BF16), ((k, n), BF16), sharding=one_chip,
+             kernels=["sddmm_tiles_call"])
 
 
 def test_bs_attn_compiles(one_chip):
@@ -128,7 +151,8 @@ def test_bs_attn_compiles(one_chip):
     mask = np.tril(np.ones((8, 8), bool))
     _compile(functools.partial(bs_attn, block_mask=mask),
              ((12, 1024, 128), BF16), ((12, 1024, 128), BF16),
-             ((12, 1024, 128), BF16), sharding=one_chip)
+             ((12, 1024, 128), BF16), sharding=one_chip,
+             kernels=["bs_attn_call"])
 
 
 @pytest.mark.parametrize("b", [4, 12])
@@ -146,7 +170,7 @@ def test_small_blocks_compile(one_chip, b):
         meta = partitioner.plan_packing(rows, cols, (m, k), b, t, t)
         return dsmm_ops.dsmm(op, x), sddmm_ops.grouped_sddmm(meta, dy, x)
     _compile(fn, ((nnz, b, b), BF16), ((k, n), BF16), ((m, n), BF16),
-             sharding=one_chip)
+             sharding=one_chip, kernels=["dsmm_call", "sddmm_tiles_call"])
 
 
 # -- the padding those compiles rely on, checked numerically ------------------
