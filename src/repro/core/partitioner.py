@@ -138,19 +138,36 @@ def plan_packing(row_idx: np.ndarray, col_idx: np.ndarray,
         nnz_blocks=len(rows))
 
 
-def pack_values(plan: PackingPlan, values) -> jax.Array:
+def pack_values(plan: PackingPlan, values, *, pad: int = 0) -> jax.Array:
     """Value phase of ``pack_tiles``: scatter ``[nnz, b, b]`` blocks into
-    the ``[T, tm, tk]`` tile stack laid out in kernel-visit order.
+    the ``[T, tm, tk]`` tile stack laid out in kernel-visit order, with
+    ``pad`` all-zero tiles appended (``[T + pad, tm, tk]``).
     Jit-compatible (metadata is host constants)."""
     b = plan.block_size
     rpb, cpb = plan.tm // b, plan.tk // b
+    t = plan.num_tiles + pad
     with jax.named_scope("pack_values"):
         vals = jnp.asarray(values)
-        tiles = jnp.zeros((plan.num_tiles, rpb, b, cpb, b), vals.dtype)
+        tiles = jnp.zeros((t, rpb, b, cpb, b), vals.dtype)
         tiles = tiles.at[jnp.asarray(plan.block_slot),
                          jnp.asarray(plan.in_r), :,
                          jnp.asarray(plan.in_c), :].add(vals)
-        return tiles.reshape(plan.num_tiles, plan.tm, plan.tk)
+        return tiles.reshape(t, plan.tm, plan.tk)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class PackedTiles:
+    """Static block-sparse values already in the bsmm kernels' layout:
+    the ``[T + 1, tm, tk]`` tile stack of ``pack_values(plan, values,
+    pad=1)`` -- kernel-visit order plus the balanced walk's zero pad
+    tile, so one stack serves both bsmm routes at every ``n``.  Forward-
+    only callers with fixed weights (a serving engine) pack once, at
+    weight-load, and pass this in place of the ``[nnz, b, b]`` values;
+    the static plans' bsmm routes then skip the per-call relayout.
+    Leading axes (a layer stack) ride along for ``jax.lax.scan``."""
+
+    tiles: jax.Array
 
 
 @dataclasses.dataclass(frozen=True)

@@ -103,14 +103,31 @@ class SparseLinear:
             over["sddmm_mode"] = self.sddmm_backend
         return dataclasses.replace(sparse_api.current_ctx(), **over)
 
+    def pack(self, values):
+        """Forward-only serving: ``values`` (``[..., nnz, b, b]``; leading
+        axes such as a layer stack are walked one matrix at a time) as
+        the bsmm kernels' tile stack (``sparse.pack``).  Put the result
+        in params as ``packed``, beside the values: ``apply`` then skips
+        the per-call relayout wherever its plan ``takes_packed``."""
+        from repro import sparse as sparse_api
+        lead, blocks = values.shape[:-3], values.shape[-3:]
+        packed = jax.lax.map(
+            lambda v: sparse_api.pack(self.as_bsr({"values": v})),
+            values.reshape((-1,) + blocks))
+        return jax.tree.map(
+            lambda t: t.reshape(lead + t.shape[1:]), packed)
+
     def apply(self, params, x: jax.Array) -> jax.Array:
         # plan-first: the pattern analysis + route decision happen once
         # per (pattern, shape) in the sparse plan cache; training steps
-        # re-enter with fresh values only
+        # re-enter with fresh values only.  A serving engine's params
+        # also carry the pre-packed tiles (``pack``), which the plan
+        # takes in place of the values where its route multiplies them
         from repro import sparse as sparse_api
         bsr = self.as_bsr(params)
         y = sparse_api.spmm_nt(bsr, x.astype(params["values"].dtype),
-                               ctx=self._plan_ctx())
+                               ctx=self._plan_ctx(),
+                               packed=params.get("packed"))
         if self.use_bias:
             y = y + params["bias"]
         return y
@@ -136,6 +153,7 @@ class SparseLinear:
             eplan = partitioner.plan_evolution(
                 old_r, old_c, new_r, new_c, new_pattern.shape)
             new_params = dict(params)
+            new_params.pop("packed", None)      # tiles of the old pattern
             new_params["values"] = partitioner.apply_evolution(
                 eplan, params["values"])
             params = new_params

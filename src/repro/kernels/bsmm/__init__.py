@@ -1,4 +1,4 @@
-from repro.kernels.bsmm.ops import bsmm, bsmm_balanced, bsmm_packed  # noqa: F401
+from repro.kernels.bsmm.ops import bsmm, bsmm_balanced  # noqa: F401
 from repro.kernels.bsmm.ref import bsmm_ref  # noqa: F401
 from repro.kernels.contract import KernelContract, register
 

@@ -50,11 +50,12 @@ def bsmm_call(tile_rows, tile_cols, tiles, x, *, tm: int, tk: int, tn: int,
     """Raw kernel entry.
 
     tile_rows/cols: [T] int32 (host constants for static mode)
-    tiles:          [T, tm, tk] packed sparse tiles
+    tiles:          [T (+ pad), tm, tk] packed sparse tiles; the walk
+                    visits the first T (trailing pad tiles are never read)
     x:              [K, N] dense operand
     returns         [grid_m * tm, N]
     """
-    t = tiles.shape[0]
+    t = tile_rows.shape[0]
     k, n = x.shape
     out_dtype = out_dtype or x.dtype
     n_tiles = n // tn

@@ -4,8 +4,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core.bsr import BlockSparseMatrix
-from repro.core.partitioner import (BalancedPacking, PackingPlan,
-                                    TilePacking, pack_tiles, pack_values,
+from repro.core.partitioner import (BalancedPacking, PackedTiles,
+                                    PackingPlan, pack_values, plan_packing,
                                     plan_packing_balanced)
 from repro.kernels.bsmm.balanced import bsmm_balanced_call
 from repro.kernels.bsmm.bsmm import bsmm_call
@@ -23,31 +23,44 @@ def _block_tile(dim: int, b: int) -> int:
     return t if t == dim or t % SUBLANE == 0 else dim
 
 
+def tile_shape(m: int, k: int, b: int):
+    """``(tm, tk)``: block-multiple row/contraction tiles dividing ``m``
+    and ``k``.  ``n`` has no part in them, so one packed tile stack
+    serves every ``n``."""
+    return _block_tile(m, b), _block_tile(k, b)
+
+
 def _pick_tiles(m: int, k: int, n: int, b: int):
-    """``(tm, tk, tn)``: block-multiple row/contraction tiles dividing
-    ``m`` and ``k``; ``tn`` from ``tiling.dim_tile`` (the executors pad
-    ``n`` to a multiple of it)."""
-    return _block_tile(m, b), _block_tile(k, b), dim_tile(n)[0]
+    """``(tm, tk, tn)``: ``tile_shape``, and ``tn`` from
+    ``tiling.dim_tile`` (the executors pad ``n`` to a multiple of it)."""
+    return (*tile_shape(m, k, b), dim_tile(n)[0])
 
 
-def bsmm_packed(packing: TilePacking, x, *, tn: int | None = None,
-                interpret: bool = False):
-    """SpMM from a pre-packed tile set (production path: pack once at
-    weight-load, multiply every step)."""
-    return lane_padded(
-        lambda xp, tn: bsmm_call(
-            jnp.asarray(packing.tile_rows), jnp.asarray(packing.tile_cols),
-            packing.values, xp, tm=packing.tm, tk=packing.tk, tn=tn,
-            grid_m=packing.grid[0], interpret=interpret), x, tn)
+def _packed(meta: PackingPlan, payload: PackedTiles):
+    """The tile stack of a pre-packed payload, checked against the
+    plan's layout (a stack packed for another pattern or tile size
+    would be read in the wrong order)."""
+    want = (meta.num_tiles + 1, meta.tm, meta.tk)
+    if tuple(payload.tiles.shape) != want:
+        raise ValueError(
+            f"packed tiles {tuple(payload.tiles.shape)} do not match this "
+            f"plan's layout {want}: pack the values of this pattern with "
+            f"sparse.pack")
+    return payload.tiles
 
 
 def bsmm_from_plan(meta: PackingPlan, values, x, *, tn: int | None = None,
                    interpret: bool = False):
     """SpMM from a one-time ``partitioner.plan_packing`` analysis: the
     pattern metadata is a baked host constant, only the value relayout
-    (``pack_values``) runs per call.  This is the ``repro.sparse``
-    plan-execute path for the ``static_pallas`` route."""
-    tiles = pack_values(meta, values)
+    (``pack_values``) runs per call -- unless ``values`` is a
+    ``PackedTiles`` payload, packed once for fixed weights, which goes
+    to the kernel as it is.  This is the ``repro.sparse`` plan-execute
+    path for the ``static_pallas`` route."""
+    if isinstance(values, PackedTiles):
+        tiles = _packed(meta, values)
+    else:
+        tiles = pack_values(meta, values)
     return lane_padded(
         lambda xp, tn: bsmm_call(
             jnp.asarray(meta.tile_rows), jnp.asarray(meta.tile_cols), tiles,
@@ -62,11 +75,15 @@ def bsmm_balanced_from_plan(meta: BalancedPacking, values, x, *,
     analysis (the ``static_balanced`` route's plan-execute path): the
     row-swizzled visit schedule is a baked host constant; per call only
     the value relayout (``pack_values``, identical to the uniform
-    route's) plus the appended zero pad tile run."""
+    route's) plus the appended zero pad tile run -- neither for a
+    ``PackedTiles`` payload, which already ends in the pad tile."""
     base = meta.base
-    tiles = pack_values(base, values)
-    tiles = jnp.concatenate(
-        [tiles, jnp.zeros((1, base.tm, base.tk), tiles.dtype)])
+    if isinstance(values, PackedTiles):
+        tiles = _packed(base, values)
+    else:
+        tiles = pack_values(base, values)
+        tiles = jnp.concatenate(
+            [tiles, jnp.zeros((1, base.tm, base.tk), tiles.dtype)])
     return lane_padded(
         lambda xp, tn: bsmm_balanced_call(
             jnp.asarray(meta.visit_rows), jnp.asarray(meta.visit_cols),
@@ -100,5 +117,7 @@ def bsmm(bsr: BlockSparseMatrix, x, *, tm: int | None = None,
     m, k = bsr.shape
     n = x.shape[-1]
     atm, atk, atn = _pick_tiles(m, k, n, bsr.block_size)
-    packing = pack_tiles(bsr, tm or atm, tk or atk)
-    return bsmm_packed(packing, x, tn=tn or atn, interpret=interpret)
+    meta = plan_packing(bsr.row_idx, bsr.col_idx, bsr.shape,
+                        bsr.block_size, tm or atm, tk or atk)
+    return bsmm_from_plan(meta, bsr.values, x, tn=tn or atn,
+                          interpret=interpret)

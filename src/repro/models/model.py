@@ -37,6 +37,24 @@ def _dtype(cfg: ModelCfg):
     return jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
 
 
+def pack_sparse(cfg: ModelCfg, params):
+    """Forward-only serving with fixed weights: ``(params', packed)``
+    where ``params'`` holds, beside each block-sparse FFN matrix's
+    values, its stack of kernel tiles (``SparseLinear.pack``) under
+    ``packed``, so ``LM.prefill``/``decode_step`` skip the per-call
+    relayout; ``params`` is left as it is.  One jitted packing per
+    matrix, over its layer stack.  ``packed`` counts the matrices (one
+    per layer) and the tiles' device bytes."""
+    served = jax.tree.map(lambda a: a, params)          # fresh containers
+    matrices = nbytes = 0
+    for gi, si, name, layer in tfm.sparse_linears(cfg):
+        p = served["stack"][gi][si]["ffn"][name]
+        p["packed"] = jax.jit(layer.pack)(p["values"])
+        matrices += int(np.prod(p["values"].shape[:-3]))
+        nbytes += int(p["packed"].tiles.nbytes)
+    return served, {"matrices": matrices, "bytes": nbytes}
+
+
 @dataclasses.dataclass(frozen=True)
 class LM:
     cfg: ModelCfg

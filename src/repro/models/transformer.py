@@ -79,6 +79,20 @@ def _sparse_ffn(cfg: ModelCfg):
                               cfg.dtype)
 
 
+def sparse_linears(cfg: ModelCfg):
+    """``(group, position, name, layer)`` of every ``SparseLinear`` in
+    the stack: its params are ``params["stack"][group][position]["ffn"]
+    [name]``, stacked over the group's repeat axis."""
+    for gi, (period, _) in enumerate(cfg.groups):
+        for si, spec in enumerate(period):
+            if spec.ffn != "sparse":
+                continue
+            up, down, gate = _sparse_ffn(cfg)._layers()
+            for name, layer in (("up", up), ("down", down), ("gate", gate)):
+                if layer is not None:
+                    yield gi, si, name, layer
+
+
 def _mixer_scope(spec: LayerSpec):
     """``attn`` around an attention mixer's call (projections, rope, KV
     write, attention); the recurrent mixer stays unscoped."""

@@ -29,10 +29,14 @@ running end to end:
 * **Async re-planner**: a background thread upgrades the pool's
   analytic route verdicts to measured ones (``sparse.remeasure_plan``)
   while serving, so cold starts never block on a measurement race.
+* **Packed weights**: serving is forward-only and its weights are
+  fixed, so every block-sparse matrix is packed into its kernel tiles
+  once, at startup (``models.model.pack_sparse``), not on every call.
 * **Live stats**: ``stats()`` / ``plan_report()["engine"]`` expose
   per-bucket prefill p50/p99 latency, decode-step p50/p99, queue depth,
-  padding waste (tokens and priced seconds), capacity overflow, and
-  ``dropped_frac`` under a bounded queue.
+  padding waste (tokens and priced seconds), capacity overflow,
+  ``dropped_frac`` under a bounded queue, and the packed matrices and
+  their bytes (``packed_matrices``, ``packed_bytes``).
 
 Termination contract: ``Request.output`` INCLUDES the token generated
 at prefill, so a request finishes once ``len(output) >=
@@ -63,7 +67,7 @@ from repro import sparse as sparse_api
 from repro.core import dispatch
 from repro.kernels.tiling import dim_tile
 from repro.models.config import ModelCfg
-from repro.models.model import LM
+from repro.models.model import LM, pack_sparse
 
 # engine pool labels must be process-unique: two engines over the same
 # checkpoint would otherwise share a pool and re-plan each other's work
@@ -191,7 +195,6 @@ class Engine:
                  replanner_interval: float = 0.25,
                  replanner_reps: int = 3):
         self.lm = lm
-        self.params = params
         self.batch = batch
         self.max_len = max_len
         self.retained = retained
@@ -221,6 +224,14 @@ class Engine:
         if plan_cache_dir is not None:
             self.plan_ctx = dataclasses.replace(
                 self.plan_ctx, cache_dir=plan_cache_dir, persist=True)
+        # fixed weights: a forward-only engine packs every block-sparse
+        # matrix into its kernel tiles once, here, and its programs take
+        # the tiles as arguments beside the values (``pack_sparse``);
+        # plans on a bsmm route then run no relayout per call
+        self.params = params
+        self.packed = {"matrices": 0, "bytes": 0}
+        if not self.plan_ctx.differentiable:
+            self.params, self.packed = pack_sparse(lm.cfg, params)
         self.caches = lm.init_cache(batch, max_len)
         self.positions = np.zeros((batch,), np.int32)
         self.live: Dict[int, Request] = {}       # slot -> request
@@ -396,6 +407,8 @@ class Engine:
         return {
             "buckets": buckets,
             "pad_safe": self.pad_safe,
+            "packed_matrices": self.packed["matrices"],
+            "packed_bytes": self.packed["bytes"],
             "queue_depth": len(self.queue),
             "peak_queue_depth": peak_queue,
             "live_slots": len(self.live),

@@ -15,6 +15,10 @@ package makes that lifecycle explicit -- two phases:
     # phase 2 (hot path): a decision-free direct call
     y = p(values, x)          # or p.apply(operand, x)
 
+    # fixed weights, forward only (serving): the values' relayout into
+    # kernel tiles runs once, not per call
+    y = sparse.spmm(operand, x, ctx=ctx, packed=sparse.pack(operand))
+
 Measured verdicts persist to a versioned on-disk cache (configure via
 ``sparse.configure(cache_dir=...)`` or $REPRO_CACHE_DIR), so serving
 restarts re-plan with zero re-measurement.
@@ -23,9 +27,11 @@ restarts re-plan with zero re-measurement.
 one-shot conveniences over the plan cache; ``repro.core.dispatch``'s
 entry points remain as deprecation shims that build-and-call a plan.
 """
+from repro.core.partitioner import PackedTiles  # noqa: F401
 from repro.sparse.cache import SCHEMA_VERSION  # noqa: F401
 from repro.sparse.plan import (  # noqa: F401
     MatmulPlan,
+    PACKED_ROUTES,
     analytic_plans,
     batched_matmul,
     cache_stats,
@@ -37,6 +43,7 @@ from repro.sparse.plan import (  # noqa: F401
     explain,
     format_plan,
     matmul,
+    pack,
     plan,
     plan_report,
     pool_plans,

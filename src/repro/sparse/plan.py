@@ -78,6 +78,9 @@ _pool_registry: Dict[str, list] = {}
 # when persistence is on, mirroring the escalation guardrail.
 _replanned: Dict[str, dict] = {}
 
+# the routes that multiply a ``partitioner.PackedTiles`` payload as it is
+PACKED_ROUTES = ("static_pallas", "static_balanced")
+
 
 def reset(*, counters: bool = True):
     """Forget every in-memory plan, decision, capacity stat, and
@@ -419,7 +422,8 @@ class MatmulPlan:
 
     Call ``plan(payload, x)`` with the per-call payload:
 
-    * static kind  -- the ``[nnz, b, b]`` values (pattern is baked in)
+    * static kind  -- the ``[nnz, b, b]`` values (pattern is baked in),
+      or, where ``takes_packed``, the ``sparse.pack`` tile stack
     * dynamic kind -- the ``DynamicOperand`` (pattern is runtime data)
     * dense kind   -- the dense weight array
 
@@ -444,12 +448,26 @@ class MatmulPlan:
     def executable(self) -> bool:
         return self._execute is not None
 
+    @property
+    def takes_packed(self) -> bool:
+        """Does this plan multiply a ``sparse.pack`` payload?  A forward-
+        only plan on a bsmm route: a packed stack carries no gradient
+        back to the values."""
+        return self.route in PACKED_ROUTES and not self.ctx.differentiable
+
     def __call__(self, payload, x) -> jax.Array:
         if self._execute is None:
             raise ValueError(
                 f"plan for {self.spec} was built from an OpSpec without a "
                 f"concrete pattern; build it from the operand to execute "
                 f"(spec-only static plans are explain/report-only)")
+        if isinstance(payload, partitioner.PackedTiles) \
+                and not self.takes_packed:
+            raise ValueError(
+                f"a plan on route {self.route!r} (differentiable="
+                f"{self.ctx.differentiable}) takes the [nnz, b, b] values; "
+                f"packed tiles serve forward-only plans on "
+                f"{PACKED_ROUTES}")
         s = self.spec
         # the contraction dim is baked into every route's metadata; a
         # mismatch must fail here, not deep inside a kernel.  (n may
@@ -1075,7 +1093,7 @@ def _static_executor(spec: OpSpec, route: str, ctx: PlanContext,
 
     if route == "static_pallas":
         from repro.kernels.bsmm import ops as bsmm_ops
-        tm, tk, _ = bsmm_ops._pick_tiles(m, k, spec.n, b)
+        tm, tk = bsmm_ops.tile_shape(m, k, b)
         meta = partitioner.plan_packing(rows, cols, (m, k), b, tm, tk)
         art.update(packing_tiles=meta.num_tiles,
                    packing_occupancy=meta.occupancy)
@@ -1086,7 +1104,7 @@ def _static_executor(spec: OpSpec, route: str, ctx: PlanContext,
 
     if route == "static_balanced":
         from repro.kernels.bsmm import ops as bsmm_ops
-        tm, tk, _ = bsmm_ops._pick_tiles(m, k, spec.n, b)
+        tm, tk = bsmm_ops.tile_shape(m, k, b)
         meta = partitioner.plan_packing_balanced(rows, cols, (m, k), b,
                                                  tm, tk)
         bal = partitioner.balance_report(meta.swizzle.loads)
@@ -1998,10 +2016,35 @@ def explain(operand_or_spec, n: Optional[int] = None, *,
     return plan(operand_or_spec, n, ctx=ctx).explain()
 
 
-def spmm(operand: Operand, x, *, ctx: Optional[PlanContext] = None):
+def pack(operand: BlockSparseMatrix) -> partitioner.PackedTiles:
+    """A static operand's values in the bsmm kernels' tile layout, for
+    forward-only callers whose weights do not change (a serving engine):
+    pack once, at weight-load, and pass the result to ``spmm`` /
+    ``spmm_nt`` as ``packed=``.  The tiles are bit-identical to what the
+    bsmm routes (``PACKED_ROUTES``) build per call; the stack ends in
+    the balanced walk's zero pad tile, so it serves both routes at every
+    ``n``.  Jit- and vmap-compatible (the pattern is host metadata)."""
+    if not (isinstance(operand, BlockSparseMatrix) and operand.is_static):
+        raise ValueError("sparse.pack takes a BlockSparseMatrix with a "
+                         "static (host) pattern")
+    from repro.kernels.bsmm import ops as bsmm_ops
+    m, k = operand.shape
+    b = operand.block_size
+    tm, tk = bsmm_ops.tile_shape(m, k, b)
+    meta = partitioner.plan_packing(
+        np.asarray(operand.row_idx, np.int32),
+        np.asarray(operand.col_idx, np.int32), (m, k), b, tm, tk)
+    return partitioner.PackedTiles(
+        partitioner.pack_values(meta, operand.values, pad=1))
+
+
+def spmm(operand: Operand, x, *, ctx: Optional[PlanContext] = None,
+         packed: Optional[partitioner.PackedTiles] = None):
     """One-shot ``Y = W @ X`` (plan + execute; the plan is cached, so
     repeated calls are dict hits -- prefer holding the plan in hot
-    loops)."""
+    loops).  ``packed`` (``sparse.pack(operand)``) stands in for the
+    values where the plan ``takes_packed``; every other plan runs the
+    operand's values."""
     ctx = _resolve_ctx(ctx)
     _, _, k, _, _ = dispatch._normalize(operand)
     if x.ndim != 2:
@@ -2009,14 +2052,17 @@ def spmm(operand: Operand, x, *, ctx: Optional[PlanContext] = None):
     if x.shape[0] != k:
         raise ValueError(f"X rows {x.shape[0]} != operand k {k}")
     p = plan(operand, int(x.shape[1]), x=x, ctx=ctx)
+    if packed is not None and p.takes_packed:
+        return p(packed, x)
     return p.apply(operand, x)
 
 
-def spmm_nt(operand: Operand, x, *, ctx: Optional[PlanContext] = None):
+def spmm_nt(operand: Operand, x, *, ctx: Optional[PlanContext] = None,
+            packed: Optional[partitioner.PackedTiles] = None):
     """Activation-major form ``x: [..., k] -> [..., m]`` (y = x @ W^T)."""
     _, m, k, _, _ = dispatch._normalize(operand)
     lead = x.shape[:-1]
-    y = spmm(operand, x.reshape(-1, k).T, ctx=ctx)
+    y = spmm(operand, x.reshape(-1, k).T, ctx=ctx, packed=packed)
     return y.T.reshape(*lead, m)
 
 

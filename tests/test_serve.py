@@ -16,7 +16,9 @@ import numpy as np
 import pytest
 
 from repro import configs, sparse as sparse_api
+from repro.core import dispatch
 from repro.core.bsr import BlockSparseMatrix
+from repro.core.sparse_layers import SparseFFN
 from repro.kernels.tiling import dim_tile
 from repro.models.model import LM
 from repro.serve import Engine, Request
@@ -333,6 +335,78 @@ def test_auto_buckets_cover_and_end_at_top():
     assert len(_auto_buckets(511, shapes, 0.25)) >= \
         len(_auto_buckets(511, shapes, 0.75))
     assert _auto_buckets(8, shapes, 0.5) == (8,)
+
+
+# -- block-sparse weights packed once, at engine start -----------------------
+
+def _bsffn_engine(**kw):
+    """A smoke-size qwen2 with the block-sparse FFN on the Pallas routes
+    (interpreted), as the engine runs it on a TPU."""
+    sparse_api.reset()
+    cfg = configs.sparse_ffn(configs.smoke("qwen2_1_5b"), 0.125)
+    lm = LM(cfg)
+    params = lm.init(jax.random.PRNGKey(0))
+    ctx = dispatch.DispatchContext(allow_pallas=True, interpret=True,
+                                   differentiable=False)
+    return Engine(lm, params, batch=2, max_len=32, dispatch_ctx=ctx,
+                  **kw), params
+
+
+def _decode_logits(eng, params):
+    tokens = jnp.asarray([[3], [5]], jnp.int32)
+    positions = jnp.asarray([4, 7], jnp.int32)
+    logits, _ = eng._decode(params, tokens, eng.caches, positions)
+    return np.asarray(logits, np.float32)
+
+
+def _routes(eng):
+    return {p.route for p in sparse_api.pool_plans(eng.pool)
+            if p.spec.kind == "static"}
+
+
+def test_engine_serves_packed_tiles():
+    eng, params = _bsffn_engine()
+    cfg = eng.lm.cfg
+    layers = sum(rep for _, rep in cfg.groups)
+    st = eng.stats()
+    assert st["packed_matrices"] == 3 * layers
+    tiles = [p["ffn"][n]["packed"].tiles for group in eng.params["stack"]
+             for p in group for n in ("up", "down", "gate")]
+    assert st["packed_bytes"] == sum(t.nbytes for t in tiles) > 0
+    assert eng.plan_report()["engine"]["packed_matrices"] == 3 * layers
+    assert _routes(eng) <= set(sparse_api.PACKED_ROUTES)
+    # the served tree adds the tiles; the caller's params keep their layout
+    assert jax.tree.structure(params) == jax.tree.structure(
+        eng.lm.init(jax.random.PRNGKey(1)))
+    np.testing.assert_array_equal(_decode_logits(eng, eng.params),
+                                  _decode_logits(eng, params))
+
+
+def test_dense_engine_packs_nothing():
+    sparse_api.reset()
+    lm = LM(configs.smoke("qwen2_1_5b"))
+    params = lm.init(jax.random.PRNGKey(0))
+    eng = Engine(lm, params, batch=2, max_len=32)
+    st = eng.stats()
+    assert (st["packed_matrices"], st["packed_bytes"]) == (0, 0)
+    assert jax.tree.structure(eng.params) == jax.tree.structure(params)
+
+
+def test_engine_off_the_bsmm_routes_decodes_from_the_values(monkeypatch):
+    want = _decode_logits(*_bsffn_engine())
+    layers = SparseFFN._layers
+    monkeypatch.setattr(SparseFFN, "_layers", lambda self: tuple(
+        None if lay is None else dataclasses.replace(lay, backend="static_xla")
+        for lay in layers(self)))
+    eng, params = _bsffn_engine()
+    assert _routes(eng) == {"static_xla"}
+    assert eng.stats()["packed_matrices"] > 0
+    got = _decode_logits(eng, eng.params)
+    np.testing.assert_array_equal(got, _decode_logits(eng, params))
+    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+    req = _req([1, 2, 3, 4, 5], max_new_tokens=3)
+    eng.run([req])
+    assert req.done and len(req.output) == 3
 
 
 # ===========================================================================

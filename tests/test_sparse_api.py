@@ -728,3 +728,112 @@ def test_abstract_mesh_plans_gspmd_only():
     p = sparse.plan(bsr, N, ctx=ctx)
     assert "static_tp_shardmap" not in p.est_seconds
     assert "static_tp" in p.est_seconds   # gspmd candidate still raced
+
+
+# -- packed payloads: the bsmm routes' tiles packed once, for fixed weights --
+
+def _empty_row_tile_bsr(dtype=jnp.bfloat16):
+    """512 x 256 in blocks of 16 (128 x 128 tiles): row-tile 1 holds no
+    block, so the walk's coverage tile and the pad tile both show."""
+    mask = np.random.default_rng(3).random((32, 16)) < 0.25
+    mask[8:16] = False
+    return BlockSparseMatrix.from_mask(mask, 16, dtype=dtype, init="normal",
+                                       key=jax.random.PRNGKey(3))
+
+
+@pytest.mark.parametrize("n", [1, 32, 200])       # 200: not a multiple of tn
+@pytest.mark.parametrize("route", sparse.PACKED_ROUTES)
+def test_packed_payload_matches_per_call_relayout(route, n):
+    bsr = _empty_row_tile_bsr()
+    x = jax.random.normal(jax.random.PRNGKey(4), (256, n)).astype(bsr.dtype)
+    p = sparse.plan(bsr, n, ctx=sparse.PlanContext(
+        mode=route, interpret=True, differentiable=False))
+    assert p.takes_packed
+    packed = sparse.pack(bsr)
+    # the kernels' layout plus one zero pad tile, one stack at every n
+    assert packed.tiles.shape == (p.artifacts["packing_tiles"] + 1, 128, 128)
+    assert not np.asarray(packed.tiles[-1], np.float32).any()
+    np.testing.assert_array_equal(np.asarray(p(packed, x), np.float32),
+                                  np.asarray(p(bsr.values, x), np.float32))
+    # and the packed call runs no relayout
+    jaxpr = str(jax.make_jaxpr(lambda t, xx: p(t, xx))(packed, x))
+    assert "scatter" not in jaxpr and "concatenate" not in jaxpr
+
+
+def test_packed_payload_is_refused_where_the_values_run():
+    bsr = _empty_row_tile_bsr()
+    x = jnp.ones((256, 8), bsr.dtype)
+    packed = sparse.pack(bsr)
+    for ctx in (sparse.PlanContext(mode="static_xla", differentiable=False),
+                sparse.PlanContext(mode="static_pallas", interpret=True,
+                                   differentiable=True)):
+        p = sparse.plan(bsr, 8, ctx=ctx)
+        assert not p.takes_packed
+        with pytest.raises(ValueError, match="packed tiles"):
+            p(packed, x)
+        # spmm runs the values there instead
+        np.testing.assert_array_equal(
+            np.asarray(sparse.spmm(bsr, x, ctx=ctx, packed=packed)),
+            np.asarray(p(bsr.values, x)))
+    # a stack packed for another pattern does not fit this plan's layout
+    other = BlockSparseMatrix.from_mask(np.ones((32, 16), bool), 16,
+                                        dtype=bsr.dtype)
+    p = sparse.plan(bsr, 8, ctx=sparse.PlanContext(
+        mode="static_pallas", interpret=True, differentiable=False))
+    with pytest.raises(ValueError, match="layout"):
+        p(sparse.pack(other), x)
+
+
+def _sparse_linear():
+    from repro.core.sparse_layers import SparseLinear
+    layer = SparseLinear.random_pattern(None, 256, 512, 16, 0.25, seed=5,
+                                        dtype=jnp.bfloat16)
+    params = layer.init(jax.random.PRNGKey(6))
+    return layer, params, dict(params, packed=layer.pack(params["values"]))
+
+
+@pytest.mark.parametrize("route", sparse.PACKED_ROUTES)
+def test_sparse_linear_apply_with_packed_tiles(route):
+    layer, params, served = _sparse_linear()
+    x = jax.random.normal(jax.random.PRNGKey(7), (3, 5, 256))
+    ctx = sparse.PlanContext(mode=route, interpret=True, differentiable=False)
+    with sparse.use_ctx(ctx):
+        want = layer.apply(params, x)
+        got = layer.apply(served, x)
+        jaxpr = str(jax.make_jaxpr(layer.apply)(served, x))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    assert "scatter" not in jaxpr
+    # a layer stack packs one matrix at a time, each as it packs alone
+    stack = jnp.stack([params["values"], 2 * params["values"]])
+    tiles = layer.pack(stack).tiles
+    assert tiles.shape[0] == 2
+    np.testing.assert_array_equal(
+        np.asarray(tiles[1], np.float32),
+        np.asarray(layer.pack(2 * params["values"]).tiles, np.float32))
+
+
+def test_differentiable_apply_ignores_packed_tiles():
+    layer, params, served = _sparse_linear()
+    x = jax.random.normal(jax.random.PRNGKey(8), (4, 256))
+    ctx = sparse.PlanContext(mode="static_pallas", interpret=True,
+                             differentiable=True)
+
+    def loss(p):
+        return jnp.sum(layer.apply(p, x).astype(jnp.float32) ** 2)
+
+    with sparse.use_ctx(ctx):
+        want = jax.grad(loss)(params)
+        got = jax.grad(loss)(served)
+    assert np.abs(np.asarray(got["values"], np.float32)).max() > 0
+    np.testing.assert_array_equal(np.asarray(got["values"], np.float32),
+                                  np.asarray(want["values"], np.float32))
+    assert not np.asarray(got["packed"].tiles, np.float32).any()
+
+
+def test_evolve_drops_tiles_packed_for_the_old_pattern():
+    layer, _, served = _sparse_linear()
+    grown = layer.pattern.copy()
+    grown[0, :] = True
+    _, params = layer.evolve(grown, served)
+    assert "packed" not in params and "values" in params

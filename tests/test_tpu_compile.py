@@ -104,6 +104,31 @@ def test_bsmm_compiles(one_chip, balanced, proj, n):
              kernels=["bsmm_balanced_call" if balanced else "bsmm_call"])
 
 
+@pytest.mark.parametrize("n", [32, 1023])
+@pytest.mark.parametrize("proj", ["up", "down"])
+@pytest.mark.parametrize("balanced", [False, True], ids=["bsmm", "balanced"])
+def test_bsmm_packed_compiles(one_chip, balanced, proj, n):
+    """The serving engine's pre-packed payload: one ``[T + 1, tm, tk]``
+    stack (``sparse.pack``) for both walks, which visit its first T
+    tiles (and the balanced walk its pad tile)."""
+    m, k = PROJ[proj]
+    rows, cols = _pattern(m, k)
+    tm, tk = bsmm_ops.tile_shape(m, k, BLOCK)
+    if balanced:
+        meta = partitioner.plan_packing_balanced(rows, cols, (m, k), BLOCK,
+                                                 tm, tk)
+        run, t = bsmm_ops.bsmm_balanced_from_plan, meta.base.num_tiles
+    else:
+        meta = partitioner.plan_packing(rows, cols, (m, k), BLOCK, tm, tk)
+        run, t = bsmm_ops.bsmm_from_plan, meta.num_tiles
+
+    def fn(tiles, x):
+        return run(meta, partitioner.PackedTiles(tiles), x)
+    _compile(fn, ((t + 1, tm, tk), BF16), ((k, n), BF16),
+             sharding=one_chip,
+             kernels=["bsmm_balanced_call" if balanced else "bsmm_call"])
+
+
 @pytest.mark.parametrize("n", [8, 1023])
 @pytest.mark.parametrize("kernel", ["dsmm", "gmm", "gmm_balanced"])
 def test_dynamic_walks_compile(one_chip, kernel, n):

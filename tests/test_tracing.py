@@ -17,6 +17,7 @@ import pytest
 
 from repro import configs
 from repro.core import dispatch
+from repro.models import transformer as tfm
 from repro.models.model import LM
 from repro.serve import Engine, Request
 
@@ -62,23 +63,39 @@ def _compiled(eng, program):
     return lowered.compile()
 
 
+def _scope_paths(compiled):
+    module = compiled.runtime_executable().hlo_modules()[0]
+    return set(ts.hlo_scopes(_msg(
+        (1, module.as_serialized_hlo_module_proto()))).values())
+
+
+def _packing_program(eng):
+    """The engine's one-time packing of its first block-sparse matrix
+    (``models.model.pack_sparse``), compiled."""
+    gi, si, name, layer = next(tfm.sparse_linears(eng.lm.cfg))
+    values = eng.params["stack"][gi][si]["ffn"][name]["values"]
+    return jax.jit(layer.pack).lower(values).compile()
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_programs_carry_the_scopes(sparse_engine, program):
     compiled = _compiled(sparse_engine, program)
     text = compiled.as_text()
-    module = compiled.runtime_executable().hlo_modules()[0]
-    paths = set(ts.hlo_scopes(_msg(
-        (1, module.as_serialized_hlo_module_proto()))).values())
-    assert {"embed", "attn", "attn/kv_update", "ffn", "ffn/pack_values",
-            "unembed"} <= paths
-    # the relayout runs inside the FFN only, and nothing else nests
-    assert {p for p in paths if "pack_values" in p} == {"ffn/pack_values"}
+    paths = _scope_paths(compiled)
+    assert {"embed", "attn", "attn/kv_update", "ffn", "unembed"} <= paths
     assert {p for p in paths if "kv_update" in p} == {"attn/kv_update"}
+    # the engine packed the block-sparse values into kernel tiles once,
+    # at start: the served programs hold no relayout
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    assert not [p for p in paths if "pack_values" in p]
+    assert not [m for m in op_names if "pack_values" in m]
     # the scopes survive the scan over layers: the loop body holds them
-    body = [m for m in re.findall(r'op_name="([^"]*)"', text)
-            if "/while/body/" in m]
-    assert any("/ffn/pack_values/" in m for m in body)
+    body = [m for m in op_names if "/while/body/" in m]
+    assert any("/ffn/" in m for m in body)
     assert any("/attn/" in m for m in body)
+    # the one-time packing program carries the relayout's scope
+    assert _scope_paths(_packing_program(sparse_engine)) - {ts.UNSCOPED} \
+        == {"pack_values"}
 
 
 def test_scope_paths_keep_the_vocabulary_in_order():
