@@ -31,8 +31,8 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -47,14 +47,14 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 sys.path.insert(0, BENCH)
 sys.path.insert(0, os.path.join(BENCH, "metrics"))
 
+import arch  # noqa: E402
 import peaks as peaks_lib  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic  # noqa: E402
-import work  # noqa: E402
 
 LOWERING = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
-POST_CLOSE_S = 60.0     # longest wait for the window's first tokens
+POST_CLOSE_S = 60.0     # longest wait after the close for what is owed
 
 
 # ---------------------------------------------------------------------------
@@ -102,17 +102,8 @@ def pair(config: str, mix: str, chips: int = 1, root: str = ROOT) -> dict:
     }
 
 
-def load_module(path: str):
-    name = "bench_" + os.path.basename(path)[:-3].replace(".", "_").replace(
-        "-", "_")
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def metric_reader(name: str):
-    return load_module(os.path.join(BENCH, "metrics", name + ".py")).read
+    return arch.load(os.path.join(BENCH, "metrics", name + ".py")).read
 
 
 # ---------------------------------------------------------------------------
@@ -121,25 +112,17 @@ def metric_reader(name: str):
 
 def program_model(c: dict):
     """The program's model configuration for a configuration file, held
-    to the file's published sizes."""
+    to the sizes that the configuration's work module
+    (``program_sizes``: attribute or dotted path to value) reads from
+    the file."""
     from repro import configs
     p = c["program"]
     cfg = configs.get(p["preset"])
     sp = c.get("sparse_ffn")
     if sp:
         cfg = configs.sparse_ffn(cfg, sp["density"])
-    g = work.dims(c)
-    want = {"d_model": g["d"], "num_layers": g["layers"],
-            "num_heads": g["heads"], "num_kv_heads": g["kv_heads"],
-            "head_dim": g["head_dim"], "d_ff": g["d_ff"],
-            "vocab_size": g["vocab"], "rope_theta": c["rope_theta"],
-            "norm_eps": c["rms_norm_eps"],
-            "tie_embeddings": c["tie_word_embeddings"],
-            "dtype": c["torch_dtype"], "qkv_bias": c["qkv_bias"],
-            "act": c["hidden_act"]}
-    if sp:
-        want["ffn_block_size"] = sp["block_size"]
-    got = {k: getattr(cfg, k) for k in want}
+    want = arch.load(c["work"]).program_sizes(c)
+    got = {k: functools.reduce(getattr, k.split("."), cfg) for k in want}
     if got != want:
         raise ValueError(f"program preset {p['preset']!r} departs from the "
                          f"configuration: {got} != {want}")
@@ -289,10 +272,18 @@ class Driver:
             self.jax.profiler.stop_trace()
         if now >= self.close:
             self.compiles_in_window = self.counter.n - self.compiles_at_open
-            waiting = [r for r in self.reqs
-                       if r["due"] < self.close and not r["req"].output]
-            if not waiting or now >= self.close + POST_CLOSE_S:
+            if not self._waiting() or now >= self.close + POST_CLOSE_S:
                 raise WindowClosed()
+
+    def _waiting(self):
+        """Requests due before the close that still owe their first token;
+        in an open loop, their last: there the check samples the requests
+        due in the window, once they have finished."""
+        if self.mix["loop"] == "open":
+            return [r for r in self.reqs
+                    if r["due"] < self.close and r["done_at"] is None]
+        return [r for r in self.reqs
+                if r["due"] < self.close and not r["req"].output]
 
     def run(self):
         mix = self.mix
@@ -342,11 +333,13 @@ def device_info(jax, chips: int, require_tpu: bool = True) -> dict:
             "count": chips}
 
 
-def sample_checked(reqs, window, n: int, seed: int) -> list:
-    """Finished requests of the window to check: the longest, and the
-    rest drawn from the seed."""
+def sample_checked(reqs, window, n: int, seed: int, by_due: bool = False
+                   ) -> list:
+    """Finished requests to check: the longest, and the rest drawn from
+    the seed; of those finished in the window, or (``by_due``, an open
+    loop) of those due in it."""
     done = [r for r in reqs if r["done_at"] is not None
-            and window[0] <= r["done_at"] <= window[1]]
+            and window[0] <= r["due" if by_due else "done_at"] <= window[1]]
     if not done:
         return []
     done.sort(key=lambda r: -(len(r["req"].prompt) + len(r["req"].output)))
@@ -368,7 +361,7 @@ def start(r: dict, seed: int, *, require_tpu: bool = True,
     from repro.models.model import LM
     from repro.serve import Engine
     c = r["config"]
-    reference = load_module(os.path.join(ROOT, c["reference"]))
+    reference = arch.load(c["reference"])
     cache_dir = compile_cache.enable()
     dep = c["deployment"]
     lm = LM(model_cfg or program_model(c))
@@ -407,7 +400,7 @@ def run_cell(r: dict, seed: int, seconds: float, trace: bool, *,
     plans = [dict(v) for v in eng.plan_report()["plans"]["per_plan"].values()]
     buckets = eng.buckets
     checked = sample_checked(drv.reqs, window, mix["check"]["requests"],
-                             seed)
+                             seed, by_due=mix["loop"] == "open")
     served = [(np.asarray(x["req"].prompt), list(x["req"].output))
               for x in checked]
     del eng, drv.eng, s["eng"]

@@ -6,12 +6,13 @@ One engine, as ``run.py`` starts it, serves the open-loop mix at each rate
 in turn for ``--seconds`` after the mix's warm-up, and drains between
 rates.  For each rate it prints one JSON line: requests due and
 admitted in the window, the backlog at the close (due, not yet
-admitted), and the time to first token, p50 and p95 over the window and
-p50 over each half of it.  A rate is sustained when the backlog stays
-small and the second half waits no longer than the first; the sweep
-stops after the first rate whose backlog passes ``STOP_BACKLOG``.  Runs
-on a TPU only, like ``run.py``; it is a tool for choosing a mix's rate,
-not part of a run.
+admitted), the admissions and output tokens per second in the window
+(what the engine completes, whatever was due), and the time to first
+token, p50 and p95 over the window and p50 over each half of it.  A
+rate is sustained when the backlog stays small and the second half
+waits no longer than the first; the sweep stops after the first rate
+whose backlog passes ``STOP_BACKLOG``.  Runs on a TPU only, like
+``run.py``; it is a tool for choosing a mix's rate, not part of a run.
 """
 from __future__ import annotations
 
@@ -58,6 +59,10 @@ def main(argv=None) -> int:
             "rate_per_s": rate, "due": len(due),
             "admitted": int(np.sum(np.asarray(first) <= w1)),
             "backlog_at_close": int(np.sum(np.asarray(first) > w1)),
+            "admits_per_s": sum(w0 <= a < w1 for a, _, _, _ in drv.admits)
+            / (w1 - w0),
+            "tok_per_s": sum(w0 <= t <= w1 for x in drv.reqs
+                             for t in x["req"].output.times) / (w1 - w0),
             "ttft_p50_ms": float(np.percentile(ttft, 50)) * 1e3,
             "ttft_p95_ms": float(np.percentile(ttft, 95)) * 1e3,
             "ttft_p50_ms_halves": [float(np.percentile(h, 50)) * 1e3

@@ -1,5 +1,6 @@
 """The harness end to end on the CPU at the smoke size of qwen2-1.5b and
-its block-sparse twin (Pallas kernels interpreted): the result line,
+its block-sparse twin (Pallas kernels interpreted), in every cell: the
+result line,
 the engine driven through ``submit`` and ``serve`` alone, ``correct``
 false when the timed path is broken underneath, and no result off a
 TPU."""
@@ -31,9 +32,10 @@ OPEN = {"loop": "open", "rate_per_s": 4.0,
         "warmup": {"seconds": 0.5}, "trace_seconds": 1,
         "check": {"requests": 4}}
 SEED = 2**31 + 12345
-CELLS = ("bsffn-decode", "dense-decode")
-# the open-loop mix, which is no cell yet (its rate is not measured)
-PREFILL = "qwen2-1.5b-bsffn.prefill-heavy"
+CELLS = ("bsffn-decode", "dense-decode", "bsffn-prefill")
+# the smoke-size mix of each cell
+MIXES = {"bsffn-decode": CLOSED, "dense-decode": CLOSED,
+         "bsffn-prefill": OPEN}
 # Limits at the smoke size, where logits are small (d_model 64): sound
 # runs read a widest gap of 0.0 to 0.006 and the float8 control 0.04 to
 # 0.05; a short CPU window finishes a few tens of tokens.
@@ -42,15 +44,8 @@ SMOKE_LIMITS = {"logit_gap_max": {"limit": 0.02},
 
 
 def smoke(workload, mix):
-    """The resolved cell with the smoke sizes of its configuration.  A
-    ``<config>.<mix>`` pair that is no cell reports the decode cells'
-    metrics."""
-    if workload in CELLS:
-        r = run.resolve(workload)
-    else:
-        r = dict(run.pair(*workload.rsplit(".", 1)),
-                 **{k: run.resolve("bsffn-decode")[k]
-                    for k in ("end_to_end", "per_layer")})
+    """The resolved cell with the smoke sizes of its configuration."""
+    r = run.resolve(workload)
     c = dict(r["config"])
     m = configs.smoke(c["program"]["preset"])
     c.update(hidden_size=m.d_model, intermediate_size=m.d_ff,
@@ -116,17 +111,38 @@ def test_closed_loop_result_line(spy):
 
 
 def test_open_loop_traced_result_line():
-    r, rec, out = one_run(PREFILL, OPEN, trace=True)
+    r, rec, out = one_run("bsffn-prefill", OPEN, trace=True)
     assert out["correct"] is True, out["checks"]
     assert rec["admits"] and rec["attempted"] > 0
-    # no device plane on the CPU: the device metrics read nothing there
-    assert {"engine.step_ms.decode", "engine.itl_p95_ms.decode"} <= set(
-        out["metrics"])
-    assert "kernel.bsmm_roofline.decode" not in out["metrics"]
-    assert "mfu.decode" not in out["metrics"]
+    # no device plane and no peaks on the CPU: the host-clock metrics
+    # read, the others read nothing there
+    assert rec["steps"]
+    assert set(out["metrics"]) == {"engine.admit_ms.prefill",
+                                   "engine.step_ms.decode"}
+    # the decode steps between admissions are read as in the decode cells
+    assert {m["name"] for m in r["per_layer"]} == {
+        "engine.admit_ms.prefill", "mfu.prefill",
+        "kernel.bsmm_roofline.prefill", "engine.step_ms.decode",
+        "mfu.decode",
+        "kernel.bsmm_roofline.decode", "kernel.dense_mm_roofline.decode",
+        "device.idle_share.decode", "model.ffn_ms.decode",
+        "model.attn_ms.decode", "engine.step_idle_ms.decode"}
+    # the share of the peak, were the CPU a TPU v5e
+    v5e = run.peaks_lib.peaks_for("TPU v5 lite")
+    assert 0 < run.metric_reader("mfu.prefill")(dict(rec, peaks=v5e)) < 100
     assert {"busy_s", "window_s"} <= set(out["device"])
     assert set(out["breakdown"]) == {"device_ops", "idle_gaps"}
     assert rec["requests"][0]["due"] <= rec["requests"][-1]["due"]
+
+
+def test_open_loop_result_line():
+    r, rec, out = one_run("bsffn-prefill", OPEN)
+    assert out["correct"] is True, out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert set(out["metrics"]) == {m["name"] for m in r["end_to_end"]} == {
+        "tok_per_s", "setup_s"}
+    assert all(v["value"] > 0 for v in out["metrics"].values())
+    assert out["compiles_in_window"] == 0
 
 
 def _token_altered(monkeypatch, vocab):
@@ -154,20 +170,21 @@ def _half_batch_left_out(monkeypatch, vocab):
     monkeypatch.setattr(LM, "decode_step", half)
 
 
-@pytest.mark.parametrize("workload", CELLS + (PREFILL,))
+@pytest.mark.parametrize("workload", CELLS)
 @pytest.mark.parametrize("fault", [_token_altered, _state_unchanged,
                                    _half_batch_left_out],
                          ids=lambda f: f.__name__.strip("_"))
 def test_broken_timed_path_is_not_correct(monkeypatch, workload, fault):
     fault(monkeypatch, configs.smoke("qwen2_1_5b").vocab_size)
-    _, rec, out = one_run(workload, OPEN if workload == PREFILL else CLOSED)
+    _, rec, out = one_run(workload, MIXES[workload])
     assert rec["checked_tokens"] >= SMOKE_LIMITS["checked_tokens"]["limit"]
     assert out["correct"] is False, out["checks"]
 
 
-def test_float8_control_is_not_correct():
+@pytest.mark.parametrize("workload", CELLS)
+def test_float8_control_is_not_correct(workload):
     """The reference in float8 in the program's place fails the limit."""
-    r, m = smoke("bsffn-decode", CLOSED)
+    r, m = smoke(workload, MIXES[workload])
     rec = run.run_cell(r, SEED, 3.0, False, require_tpu=False, model_cfg=m,
                        control=True)
     assert run.is_correct(run.checks(rec, SMOKE_LIMITS))

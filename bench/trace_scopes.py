@@ -55,6 +55,8 @@ UNSCOPED = "(unscoped)"
 ENGINE = "engine."
 OUTSIDE = "(no engine span)"
 DECODE = "jit_decode_fn"
+PREFILL = "jit_prefill_fn"
+ADMIT = "engine.admit"
 # For a program built before the named scopes: the functions they wrap,
 # and, since a program keeps only the innermost ten frames of each
 # operation's source stack, the sparse FFN's own layers (the relayout
@@ -315,6 +317,17 @@ def load(trace_dir: str) -> dict:
     return {"device": dev, "host": host}
 
 
+def _tokens(admits, t: float):
+    """The tokens prefilled by the program that runs at ``t``: the
+    bucket of the ``engine.admit`` span around it (the prompt's length
+    where the engine admits it unpadded), ``None`` outside one."""
+    for a, b, args in admits:
+        if a <= t <= b:
+            bucket = args.get("bucket")
+            return args.get("prompt_len") if bucket == "exact" else bucket
+    return None
+
+
 def _idle_within(gaps: np.ndarray):
     """``f(a, b)``: the idle time that falls inside ``[a, b]``, for
     sorted disjoint ``gaps`` ``[[start, end], ...]``."""
@@ -344,7 +357,11 @@ def reduce(events: dict) -> dict:
     ``engine.`` span that holds it (``(no engine span)`` elsewhere).
     ``scoped``: whether any operation in those programs had a scope
     path at all, which a trace without compiled programs (the CPU's)
-    has not."""
+    has not.  ``prefills``: ``[(n, {operation: seconds})]``, one entry
+    for each prefill program that ran whole in the window, with the
+    device time of its operations by short name and the tokens it
+    prefilled, the bucket of the ``engine.admit`` span that holds it
+    (``None`` where no span does)."""
     host, dev = events["host"], events["device"]
     win = [h for h in host if h["name"] == trace_reduce.WINDOW]
     if win:
@@ -360,6 +377,9 @@ def reduce(events: dict) -> dict:
     runs = collections.defaultdict(lambda: [0, 0.0])
     scope_s = collections.defaultdict(lambda: collections.defaultdict(float))
     scoped = False
+    admits = [(h["t0"], h["t0"] + h["dur"], h.get("args", {})) for h in host
+              if h["name"] == ADMIT]
+    prefills = []
     for d in devices:
         mods = sorted((e["t0"], e["t0"] + e["dur"], program(e["name"]))
                       for e in dev if e["dev"] == d
@@ -367,6 +387,7 @@ def reduce(events: dict) -> dict:
                       and w0 <= e["t0"] and e["t0"] + e["dur"] <= w1)
         starts = [m[0] for m in mods]
         spans = collections.defaultdict(list)      # program -> spans
+        op_s = [collections.defaultdict(float) for _ in mods]
         for e in ops:
             if e["dev"] != d:
                 continue
@@ -377,9 +398,13 @@ def reduce(events: dict) -> dict:
             if e["scope"] not in (None, UNSCOPED):
                 scoped = True
             spans[mods[i][2]].append((a, b, e["scope"] or UNSCOPED))
-        for a, b, prog in mods:
+            op_s[i][e["name"]] += (b - a) * 1e-9
+        for (a, b, prog), spent in zip(mods, op_s):
             runs[prog][0] += 1
             runs[prog][1] += (b - a) * 1e-9
+            if prog == PREFILL:
+                prefills.append((_tokens(admits, 0.5 * (a + b)),
+                                 dict(spent)))
         for prog, sp in spans.items():
             for scope, t in trace_reduce._self_times(sp).items():
                 scope_s[prog][scope] += t * 1e-9
@@ -387,7 +412,8 @@ def reduce(events: dict) -> dict:
     out = {"window_s": (w1 - w0) * 1e-9,
            "runs": {k: tuple(v) for k, v in runs.items()},
            "scope_s": {k: dict(v) for k, v in scope_s.items()},
-           "scoped": scoped, "steps": 0, "step_idle_s": None,
+           "scoped": scoped, "prefills": prefills,
+           "steps": 0, "step_idle_s": None,
            "idle_s": None, "idle_by_span": {}}
     if not devices:
         return out      # no device operation: no idle to place

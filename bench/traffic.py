@@ -7,7 +7,9 @@ order is the seed's own too, except for the sizes that a mix names in
 ``same_order``: those come in one order for every seed.  In a closed
 loop the output lengths alone decide when a slot frees and an admission
 runs, so a mix that names them does the same work in its window on
-every seed.
+every seed.  In an open loop near the engine's capacity the order of
+the arrival gaps decides when a backlog builds, so a mix there names
+them too.
 
 A mix file holds:
 
@@ -23,7 +25,8 @@ A mix file holds:
 * ``check``: ``{"requests": n}``, how many finished requests the
   reference checks;
 * ``same_order`` (optional): the sizes (``"prompt_len"``,
-  ``"output_len"``) dealt in one order for every seed.
+  ``"output_len"``) and, in an open loop, the ``"arrival_gap"``s dealt
+  in one order for every seed.
 """
 from __future__ import annotations
 
@@ -100,7 +103,9 @@ def build(mix: dict, seed: int, seconds: float, vocab: int) -> list:
         # exponential gaps at stratified quantiles: mean 1 / rate; every
         # seed's last arrival falls at the same time
         q = (np.arange(n) + 0.5) / n
-        gaps = rng.permutation(-np.log1p(-q) / mix["rate_per_s"])
+        order = np.random.default_rng([2]) if "arrival_gap" in fixed \
+            else rng
+        gaps = order.permutation(-np.log1p(-q) / mix["rate_per_s"])
         due = np.cumsum(gaps)
         for s, t in zip(specs, due):
             s.due = float(t)

@@ -1,5 +1,12 @@
-"""Operations and bytes the model's work needs, from the configuration's
-own sizes (the JSON under ``bench/configs``), never from the program.
+"""The Qwen2 architecture's work module (a configuration's ``"work"``,
+loaded through ``bench/arch.py``): the sizes the program's model must
+have, and the operations and bytes the model's work needs, from the
+configuration's own sizes (the JSON under ``bench/configs``), never from
+the program.
+
+``roofline_s``, ``matmul_call``, ``block_mask``, ``BF16`` and ``F32``
+know no architecture; another architecture's work module imports them
+from here.
 
 Counts are of useful work: a sparse matrix counts its nonzero blocks, a
 decode step its live slots and the cache positions they attend, a
@@ -20,6 +27,23 @@ def dims(c: dict) -> dict:
     return {"layers": c["num_hidden_layers"], "d": d, "heads": h,
             "kv_heads": c["num_key_value_heads"], "head_dim": d // h,
             "d_ff": c["intermediate_size"], "vocab": c["vocab_size"]}
+
+
+def program_sizes(c: dict) -> dict:
+    """The program ``ModelCfg`` attributes that must equal the file's
+    published sizes."""
+    g = dims(c)
+    want = {"d_model": g["d"], "num_layers": g["layers"],
+            "num_heads": g["heads"], "num_kv_heads": g["kv_heads"],
+            "head_dim": g["head_dim"], "d_ff": g["d_ff"],
+            "vocab_size": g["vocab"], "rope_theta": c["rope_theta"],
+            "norm_eps": c["rms_norm_eps"],
+            "tie_embeddings": c["tie_word_embeddings"],
+            "dtype": c["torch_dtype"], "qkv_bias": c["qkv_bias"],
+            "act": c["hidden_act"]}
+    if c.get("sparse_ffn"):
+        want["ffn_block_size"] = c["sparse_ffn"]["block_size"]
+    return want
 
 
 def block_mask(m: int, k: int, b: int, density: float, seed: int):
